@@ -13,6 +13,8 @@ import json
 import subprocess
 import sys
 
+from repro.utils import cpu_child_env
+
 _CHILD = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
@@ -39,8 +41,7 @@ print("RESULT " + json.dumps(coll))
 def main() -> bool:
     r = subprocess.run(
         [sys.executable, "-c", _CHILD], capture_output=True, text=True,
-        env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
-             "HOME": "/root"}, cwd=".",
+        env=cpu_child_env(), cwd=".",
     )
     line = [l for l in r.stdout.splitlines() if l.startswith("RESULT ")]
     if not line:

@@ -30,6 +30,8 @@ import os
 import subprocess
 import sys
 
+from repro.utils import cpu_child_env
+
 MESHES = [(1, 1), (1, 2), (1, 4), (2, 2)]
 
 _CHILD = r"""
@@ -98,8 +100,7 @@ print("RESULT " + json.dumps(res))
 def main() -> bool:
     print(f"\n[ISSUE 10] sharded paged serving: packkv paged engine at "
           f"{MESHES} on 8 host-platform devices")
-    env = {"PYTHONPATH": "src", "PATH": "/usr/bin:/bin", "HOME": "/root",
-           "BENCH_SHARDED_MESHES": json.dumps(MESHES)}
+    env = {**cpu_child_env(), "BENCH_SHARDED_MESHES": json.dumps(MESHES)}
     r = subprocess.run([sys.executable, "-c", _CHILD], capture_output=True,
                        text=True, env=env, cwd=".", timeout=1800)
     lines = [l for l in r.stdout.splitlines() if l.startswith("RESULT ")]

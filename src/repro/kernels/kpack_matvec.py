@@ -1,10 +1,11 @@
 """Fused K decompress + q·Kᵀ Pallas kernel (paper Fig. 8, TPU-adapted).
 
 One ``pallas_call`` per width tier covers ALL (batch × kv-head) rows and all
-context tiles in a single launch — the TPU analogue of the paper's
-single-kernel decompression (§III-B4): grid = (B·H_kv, L/TL).
+context tokens in a single launch — the TPU analogue of the paper's
+single-kernel decompression (§III-B4): grid = (B·H_kv, n_blocks), blocks as
+in ``pallas_utils`` (a dense row or one paged page).
 
-Each grid cell decodes a [C_t, TL] integer tile from packed u32 words in
+Each compute tile decodes a [C_t, TL] integer tile from packed u32 words in
 VMEM and contracts it with the [G, C_t] query slice on the MXU, producing
 the [G, TL] integer-score tile. Per-token (scale, zero) are folded outside
 as rank-1 corrections (see kernels/ref.py docstring) so decompressed data
@@ -19,11 +20,13 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from .pallas_utils import (
-    load_page_id,
-    load_tier_pool_tile,
-    page_table_spec,
-    pool_block_spec,
-    tpu_params,
+    packed_call,
+    pool_page_spec,
+    row_block_spec,
+    row_spec,
+    rows_valid,
+    tile_slices,
+    token_iota,
 )
 from .unpack import decode_tier_tile
 
@@ -32,38 +35,36 @@ Array = jax.Array
 DEFAULT_TILE_L = 256
 
 
-def _kernel(*refs, width, pack, masked, tile_l):
-    if masked:
-        payload_ref, mins_ref, shifts_ref, q_ref, n_ref, out_ref = refs
-    else:
-        payload_ref, mins_ref, shifts_ref, q_ref, out_ref = refs
-        n_ref = None
+def _kernel(n_ref, payload_ref, mins_ref, shifts_ref, q_ref, out_ref, *,
+            width, pack, tile_l, block_l):
+    b, j = pl.program_id(0), pl.program_id(1)  # outside pl.when (interpret)
+    n_live = n_ref[b]
 
-    tile_start = pl.program_id(1) * tile_l  # outside pl.when (interpret mode)
+    for s in range(block_l // tile_l):
+        start = j * block_l + s * tile_l
+        tok = slice(s * tile_l, (s + 1) * tile_l)
 
-    def compute():
-        vals = decode_tier_tile(
-            payload_ref[0], mins_ref[0], shifts_ref[0], width, pack
-        )  # [C, TL] f32
-        q = q_ref[0]  # [G, C] f32
-        out = jax.lax.dot_general(
-            q, vals, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        if n_ref is not None:
-            gidx = tile_start + jnp.arange(tile_l)
-            out = jnp.where((gidx < n_ref[0, 0])[None, :], out, 0.0)
-        out_ref[0] = out
+        def compute(s=s, start=start, tok=tok):
+            ws, ps, bs = tile_slices(s, tile_l, width, pack)
+            vals = decode_tier_tile(payload_ref[:, ws], mins_ref[:, ps],
+                                    shifts_ref[:, bs], width, pack)  # [C, TL]
+            out = jax.lax.dot_general(
+                q_ref[...], vals, (((1,), (0,)), ((), ())),
+                precision=jax.lax.Precision.HIGHEST,
+                preferred_element_type=jnp.float32,
+            )
+            out_ref[:, tok] = jnp.where(token_iota(start, tile_l) < n_live,
+                                        out, 0.0)
 
-    if n_ref is None:
-        compute()
-        return
-    # tile skipping: a tile starting at/past this row's valid length is all
-    # masked — write its (zero) output without decoding or touching the MXU
-    live = tile_start < n_ref[0, 0]
-    pl.when(live)(compute)
-    pl.when(jnp.logical_not(live))(
-        lambda: out_ref.__setitem__(..., jnp.zeros_like(out_ref))
-    )
+        def dead(tok=tok):
+            out_ref[:, tok] = jnp.zeros((out_ref.shape[0], tile_l),
+                                        out_ref.dtype)
+
+        # tile skipping: a tile starting at/past this row's valid length is
+        # all masked — write its (zero) output without decoding or the MXU
+        live = start < n_live
+        pl.when(live)(compute)
+        pl.when(jnp.logical_not(live))(dead)
 
 
 def kpack_tier_scores(
@@ -76,7 +77,6 @@ def kpack_tier_scores(
     pack_size: int,
     n_valid: Array | None = None,
     tile_l: int = DEFAULT_TILE_L,
-    interpret: bool = True,
 ) -> Array:
     """Integer score contribution of one tier.
 
@@ -91,63 +91,18 @@ def kpack_tier_scores(
     L = Wl * (32 // width)
     tile_l = min(tile_l, L)  # bucketed launches may slice below the tile
     assert L % tile_l == 0 and tile_l % (pack_size * 4) == 0
-    nL = L // tile_l
-    tWl = tile_l * width // 32
-    tP = tile_l // pack_size
-
-    in_specs = [
-        pl.BlockSpec((1, C, tWl), lambda b, l: (b, 0, l)),
-        pl.BlockSpec((1, C, tP), lambda b, l: (b, 0, l)),
-        pl.BlockSpec((1, C, tP // 4), lambda b, l: (b, 0, l)),
-        pl.BlockSpec((1, G, C), lambda b, l: (b, 0, 0)),
-    ]
-    args = [payload, mins, shifts, q]
-    if n_valid is not None:
-        in_specs.append(pl.BlockSpec((1, 1), lambda b, l: (b, 0)))
-        args.append(n_valid.astype(jnp.int32).reshape(BH, 1))
-
-    grid = (BH, nL)
-    return pl.pallas_call(
+    nv = rows_valid(L if n_valid is None else n_valid, BH, 1)
+    return packed_call(
         functools.partial(_kernel, width=width, pack=pack_size,
-                          masked=n_valid is not None, tile_l=tile_l),
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, G, tile_l), lambda b, l: (b, 0, l)),
+                          tile_l=tile_l, block_l=L),
+        grid=(BH, 1),
+        in_specs=[row_spec(payload.shape[1:]), row_spec(mins.shape[1:]),
+                  row_spec(shifts.shape[1:]), row_spec((G, C))],
+        out_specs=row_block_spec(G, L),
         out_shape=jax.ShapeDtypeStruct((BH, G, L), jnp.float32),
-        interpret=interpret,
-        **tpu_params(("parallel", "parallel"), interpret),
-    )(*args)
-
-
-def _paged_kernel(payload_ref, mins_ref, shifts_ref, q_ref, n_ref, tab_ref,
-                  out_ref, *, width, pack, tile_l, tiles_per_page):
-    """Paged tier scores: each grid step resolves one context tile's
-    physical page through the page table (see packed_attention.py for the
-    whole-pool-ref interpret-mode caveat)."""
-    pid = pl.program_id(1)  # outside pl.when (interpret mode)
-    tile_start = pid * tile_l
-    lp = pid // tiles_per_page
-    toff = pid % tiles_per_page
-
-    def compute():
-        phys = load_page_id(tab_ref, lp)
-        vals = decode_tier_tile(
-            *load_tier_pool_tile(payload_ref, mins_ref, shifts_ref, phys,
-                                 toff, tile_l, width, pack),
-            width, pack,
-        )  # [C, TL] f32
-        out = jax.lax.dot_general(
-            q_ref[0], vals, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        gidx = tile_start + jnp.arange(tile_l)
-        out_ref[0] = jnp.where((gidx < n_ref[0, 0])[None, :], out, 0.0)
-
-    # tile skipping: dead tiles never resolve their page id
-    live = tile_start < n_ref[0, 0]
-    pl.when(live)(compute)
-    pl.when(jnp.logical_not(live))(
-        lambda: out_ref.__setitem__(..., jnp.zeros_like(out_ref))
+        scalars=[nv],
+        operands=[payload, mins, shifts, q],
+        dimension_semantics=("parallel", "parallel"),
     )
 
 
@@ -164,7 +119,6 @@ def kpack_tier_scores_paged(
     pack_size: int,
     page_size: int,
     tile_l: int = DEFAULT_TILE_L,
-    interpret: bool = True,
 ) -> Array:
     """One tier's integer scores over a PAGED pool.
 
@@ -175,30 +129,23 @@ def kpack_tier_scores_paged(
     Returns si f32 [BH, G, n_tokens] — bit-identical to ``kpack_tier_scores``
     on the gathered dense view.
     """
-    h_kv, P = payload.shape[0], payload.shape[1]
+    h_kv = payload.shape[0]
     BH, G, C = q.shape
     tile_l = min(tile_l, page_size)
     assert page_size % tile_l == 0 and tile_l % (pack_size * 4) == 0
     assert n_tokens % page_size == 0 and n_tokens >= page_size
     n_pg = n_tokens // page_size
-    tpp = page_size // tile_l
-
-    in_specs = [
-        pool_block_spec(payload, h_kv),
-        pool_block_spec(mins, h_kv),
-        pool_block_spec(shifts, h_kv),
-        pl.BlockSpec((1, G, C), lambda b, l: (b, 0, 0)),
-        pl.BlockSpec((1, 1), lambda b, l: (b, 0)),
-        page_table_spec(n_pg, h_kv),
-    ]
-    return pl.pallas_call(
-        functools.partial(_paged_kernel, width=width, pack=pack_size,
-                          tile_l=tile_l, tiles_per_page=tpp),
-        grid=(BH, n_pg * tpp),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, G, tile_l), lambda b, l: (b, 0, l)),
+    page = lambda x: pool_page_spec(x, h_kv, n_pg, page_size)
+    return packed_call(
+        lambda n_ref, tab_ref, *refs: _kernel(
+            n_ref, *refs, width=width, pack=pack_size, tile_l=tile_l,
+            block_l=page_size),
+        grid=(BH, n_pg),
+        in_specs=[page(payload), page(mins), page(shifts), row_spec((G, C))],
+        out_specs=row_block_spec(G, page_size),
         out_shape=jax.ShapeDtypeStruct((BH, G, n_tokens), jnp.float32),
-        interpret=interpret,
-        **tpu_params(("parallel", "parallel"), interpret),
-    )(payload, mins, shifts, q,
-      n_valid.astype(jnp.int32).reshape(BH, 1), page_table[:, :n_pg])
+        scalars=[n_valid.astype(jnp.int32).reshape(BH),
+                 page_table[:, :n_pg].reshape(-1).astype(jnp.int32)],
+        operands=[payload, mins, shifts, q],
+        dimension_semantics=("parallel", "parallel"),
+    )

@@ -5,8 +5,9 @@ Backend dispatch:
     decode+matvec, and the cache bytes read from HBM are the compressed
     bytes, so the paper's bandwidth argument holds; this is the default on
     CPU and the path the production dry-run lowers.
-  * ``"pallas"`` — explicit Pallas kernels (interpret=True on CPU,
-    compiled on TPU): single-launch fused decode attention.
+  * ``"pallas"`` — explicit Pallas kernels (interpreted on CPU, compiled
+    on TPU — ``pallas_utils.interpret_mode``): single-launch fused decode
+    attention.
 """
 from __future__ import annotations
 
@@ -22,6 +23,7 @@ from ..core.tiered import (
 from . import ref
 from .kpack_matvec import kpack_tier_scores, kpack_tier_scores_paged
 from .packed_attention import fused_packed_attention, fused_packed_attention_paged
+from .pallas_utils import rows_valid
 from .vpack_matvec import vpack_tier_out, vpack_tier_out_paged
 
 Array = jax.Array
@@ -31,12 +33,7 @@ NEG_INF = ref.NEG_INF
 
 def _rows_to_bh(n: Array | None, B: int, h_kv: int) -> Array | None:
     """Broadcast scalar/[B] valid counts to the kernels' flat [BH] layout."""
-    if n is None:
-        return None
-    n = jnp.asarray(n, jnp.int32)
-    if n.ndim == 0:
-        n = n[None]
-    return jnp.broadcast_to(n[:, None], (B, h_kv)).reshape(B * h_kv)
+    return None if n is None else rows_valid(n, B, h_kv)
 
 
 def packed_qk_scores(
@@ -47,7 +44,6 @@ def packed_qk_scores(
     n_valid: Array | None = None,
     backend: str = "xla",
     tile_l: int = 256,
-    interpret: bool = True,
 ) -> Array:
     """q·Kᵀ over the compressed K cache. q: [B,H,D] -> scores [B,H,L].
 
@@ -76,7 +72,6 @@ def packed_qk_scores(
         si = si + kpack_tier_scores(
             flat(t.payload), flat(t.mins), flat(t.shifts), qf[..., off : off + c],
             n_valid=nv, width=t.width, pack_size=t.pack_size, tile_l=tile_l,
-            interpret=interpret,
         )
         off += c
     qsum = jnp.sum(qf, axis=-1, keepdims=True)
@@ -96,7 +91,6 @@ def packed_weighted_v(
     n_valid: Array | None = None,
     backend: str = "xla",
     tile_l: int = 256,
-    interpret: bool = True,
 ) -> Array:
     """w·V over the compressed V cache. w: [B,H,L] -> out [B,H,D].
 
@@ -120,7 +114,6 @@ def packed_weighted_v(
         vpack_tier_out(
             flat(t.payload), flat(t.mins), flat(t.shifts), ws,
             n_valid=nv, width=t.width, pack_size=t.pack_size, tile_l=tile_l,
-            interpret=interpret,
         )
         for t in vc.tiers
     ]
@@ -128,7 +121,7 @@ def packed_weighted_v(
     # zero-term correction runs outside the kernel -> mask its weights here
     if nv is not None:
         wf = jnp.where(ref.valid_mask(nv, L, lead=2), wf, 0.0)
-    zterm = jnp.einsum("bgl,bl->bg", wf, flat(vc.zero))[..., None]
+    zterm = ref.einsum("bgl,bl->bg", wf, flat(vc.zero))[..., None]
     out = out + zterm
     out = out.reshape(B, h_kv, G, -1)
     inv = chan_inverse_perm(vc.chan_perm)
@@ -146,7 +139,6 @@ def packed_qk_scores_paged(
     n_valid: Array,
     backend: str = "xla",
     tile_l: int = 256,
-    interpret: bool = True,
 ) -> Array:
     """``packed_qk_scores`` over a PAGED K cache.
 
@@ -179,7 +171,6 @@ def packed_qk_scores_paged(
             t.payload, t.mins, t.shifts, qf[..., off : off + c],
             pages.page_table, nv, n_tokens, width=t.width,
             pack_size=t.pack_size, page_size=pages.page_size, tile_l=tile_l,
-            interpret=interpret,
         )
         off += c
     qsum = jnp.sum(qf, axis=-1, keepdims=True)
@@ -199,7 +190,6 @@ def packed_weighted_v_paged(
     n_valid: Array,
     backend: str = "xla",
     tile_l: int = 256,
-    interpret: bool = True,
 ) -> Array:
     """``packed_weighted_v`` over a PAGED V cache.
 
@@ -227,13 +217,13 @@ def packed_weighted_v_paged(
         vpack_tier_out_paged(
             t.payload, t.mins, t.shifts, ws, pages.page_table, nv,
             width=t.width, pack_size=t.pack_size, page_size=pages.page_size,
-            tile_l=tile_l, interpret=interpret,
+            tile_l=tile_l,
         )
         for t in vc.tiers
     ]
     out = jnp.concatenate(parts, axis=-1)  # [BH, G, Dv] tier order
     wf = jnp.where(ref.valid_mask(nv, n_tokens, lead=2), wf, 0.0)
-    zterm = jnp.einsum("bgl,bl->bg", wf, flatm(vc.zero))[..., None]
+    zterm = ref.einsum("bgl,bl->bg", wf, flatm(vc.zero))[..., None]
     out = out + zterm
     out = out.reshape(B, h_kv, G, -1)
     inv = chan_inverse_perm(vc.chan_perm)
@@ -250,13 +240,13 @@ def _residual_partials(q, resid_k, resid_v, n_resid, sm_scale):
     h_kv = resid_k.shape[1]
     R = resid_k.shape[2]
     qg = q.astype(jnp.float32).reshape(B, h_kv, H // h_kv, D)
-    s = jnp.einsum("bhgd,bhrd->bhgr", qg, resid_k.astype(jnp.float32)) * sm_scale
+    s = ref.einsum("bhgd,bhrd->bhgr", qg, resid_k.astype(jnp.float32)) * sm_scale
     mask = ref.valid_mask(n_resid, R, lead=3)
     s = jnp.where(mask, s, NEG_INF)
     m = jnp.max(s, axis=-1)
     p = jnp.where(mask, jnp.exp(s - m[..., None]), 0.0)
     l = jnp.sum(p, axis=-1)
-    o = jnp.einsum("bhgr,bhrd->bhgd", p, resid_v.astype(jnp.float32))
+    o = ref.einsum("bhgr,bhrd->bhgd", p, resid_v.astype(jnp.float32))
     return o.reshape(B, H, D), m.reshape(B, H), l.reshape(B, H)
 
 
@@ -281,7 +271,6 @@ def packed_decode_attention(
     *,
     backend: str = "xla",
     tile_l: int = 256,
-    interpret: bool = True,
 ) -> Array:
     """Full decode attention over [compressed | residual] regions."""
     if backend == "xla":
@@ -289,7 +278,7 @@ def packed_decode_attention(
             q, kc, vc, resid_k, resid_v, n_comp, n_resid, sm_scale
         )
     o_c, m_c, l_c = fused_packed_attention(
-        q, kc, vc, n_comp, sm_scale, tile_l=tile_l, interpret=interpret
+        q, kc, vc, n_comp, sm_scale, tile_l=tile_l
     )
     o_r, m_r, l_r = _residual_partials(q, resid_k, resid_v, n_resid, sm_scale)
     return merge_partials(o_c, m_c, l_c, o_r, m_r, l_r)
@@ -303,7 +292,6 @@ def paged_decode_attention(
     n_bucket: int | None = None,
     backend: str = "xla",
     tile_l: int = 256,
-    interpret: bool = True,
 ) -> Array:
     """Full decode attention over a PAGED compressed cache + residual.
 
@@ -325,7 +313,6 @@ def paged_decode_attention(
     o_c, m_c, l_c = fused_packed_attention_paged(
         q, cache.k, cache.v, cache.pages.page_table, cache.n_comp, n_tokens,
         sm_scale, page_size=cache.cfg.page_size, tile_l=tile_l,
-        interpret=interpret,
     )
     o_r, m_r, l_r = _residual_partials(
         q, cache.resid_k, cache.resid_v, cache.n_resid, sm_scale

@@ -9,25 +9,22 @@ three [G] statistics used to merge with the full-precision residual
 buffer via log-sum-exp (the deterministic TPU replacement for the paper's
 fp32 ``atomicAdd`` partial sums).
 
-Grid = (B·H_kv, L/TL): grid dim 0 parallel over heads/batch, dim 1
-sequential over context tiles (the flash recurrence).
+Grid = (B·H_kv, n_blocks): grid dim 0 parallel over heads/batch, dim 1
+sequential over context blocks, each walked in ``tile_l``-token compute
+tiles (the flash recurrence). Inputs are generated programmatically from
+the K/V tier specs, so any TierSpec combination lowers to a single kernel.
 
-Inputs are generated programmatically from the K/V tier specs, so any
-TierSpec combination lowers to a single kernel.
+Two storage modes share one kernel body (``_attention_kernel``) and differ
+only in their block specs (``pallas_utils``):
 
-Two storage modes share the flash tile update (``_flash_tile_body``):
-
-* ``fused_packed_attention`` — dense per-slot buffers, context tiles
-  blocked by the BlockSpec grid (the PR-3 layout).
+* ``fused_packed_attention`` — dense per-slot buffers; one block is the
+  row's whole token axis.
 * ``fused_packed_attention_paged`` — the compressed bytes live in a shared
-  page pool; each grid step resolves its logical page through the slot's
-  page table (``pl.load`` on the table, then a dynamic page load from the
-  pool — tile_l divides page_size, so one step reads one physical page).
-  Per-token scale/zero are gathered to the dense layout outside the kernel
-  (rank-1 metadata, bucket-sized); only the payload/mins/shifts pools are
-  indexed in-kernel. NOTE: under interpret mode (this repo's CI) the pool
-  rides in as a whole-array ref; a real TPU lowering would move the page
-  table to scalar prefetch so only the addressed page is DMA'd into VMEM.
+  page pool; one block is one physical page, resolved through the slot's
+  page table (scalar prefetch) in the block index map. Per-token
+  scale/zero are gathered to the dense layout outside the kernel (rank-1
+  metadata, bucket-sized). With equal ``tile_l`` both modes run the same
+  tiles in the same order, so their outputs are bit-identical.
 """
 from __future__ import annotations
 
@@ -39,11 +36,13 @@ from jax.experimental import pallas as pl
 
 from ..core.tiered import TieredCache
 from .pallas_utils import (
-    load_page_id,
-    load_tier_pool_tile,
-    page_table_spec,
-    pool_block_spec,
-    tpu_params,
+    packed_call,
+    pool_page_spec,
+    row_block_spec,
+    row_spec,
+    rows_valid,
+    tile_slices,
+    token_iota,
 )
 from .unpack import decode_tier_tile
 
@@ -72,44 +71,50 @@ def _flash_tile_body(
     v_offs,
     sm_scale,
 ):
-    """One context tile's flash update, shared by the dense and paged
-    kernels. ``k_tiles``/``v_tiles`` are the decoded integer tiles
-    ([C_t, TL] f32 per tier); ``*_t`` are the tile's per-token metadata
-    ([TL] f32); ``gidx`` the global token indices of the tile."""
+    """One context tile's flash update. ``k_tiles``/``v_tiles`` are the
+    decoded integer tiles ([C_t, TL] f32 per tier); ``*_t`` are the tile's
+    per-token metadata ([1, TL] f32); ``gidx`` the tile's global token
+    indices [1, TL]; the running statistics are [G, 1]."""
     # ---- K: integer scores for this tile ----------------------------------
     si = None
     for t, vals in enumerate(k_tiles):
         qs = q[:, k_offs[t] : k_offs[t + 1]]  # [G, Ck_t]
         d = jax.lax.dot_general(
-            qs, vals, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+            qs, vals, (((1,), (0,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32,
         )
         si = d if si is None else si + d  # [G, TL]
     qsum = jnp.sum(q, axis=-1, keepdims=True)  # [G, 1]
-    scores = (si * kscale_t[None, :] + qsum * kzero_t[None, :]) * sm_scale
+    scores = (si * kscale_t + qsum * kzero_t) * sm_scale
 
-    valid = (gidx < n_live).astype(jnp.float32)[None, :]  # [1, TL]
+    valid = (gidx < n_live).astype(jnp.float32)  # [1, TL]
     scores = jnp.where(valid > 0, scores, NEG_INF)
 
     # ---- online softmax ----------------------------------------------------
-    m_prev = m_ref[0]  # [G]
-    m_new = jnp.maximum(m_prev, jnp.max(scores, axis=-1))
-    alpha = jnp.exp(m_prev - m_new)  # [G]
-    p = jnp.exp(scores - m_new[:, None]) * valid  # [G, TL]
-    l_ref[0] = l_ref[0] * alpha + jnp.sum(p, axis=-1)
-    m_ref[0] = m_new
+    m_prev = m_ref[...]  # [G, 1]
+    m_new = jnp.maximum(m_prev, jnp.max(scores, axis=-1, keepdims=True))
+    alpha = jnp.exp(m_prev - m_new)  # [G, 1]
+    p = jnp.exp(scores - m_new) * valid  # [G, TL]
+    l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+    m_ref[...] = m_new
 
     # ---- V: weighted accumulation ------------------------------------------
-    ws = p * vscale_t[None, :]  # fold per-token scale into weights
-    acc_ref[0] *= alpha[:, None]
+    ws = p * vscale_t  # fold per-token scale into weights
+    acc_ref[...] *= alpha
     for t, vals in enumerate(v_tiles):
         d = jax.lax.dot_general(
-            ws, vals, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+            ws, vals, (((1,), (1,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32,
         )  # [G, Cv_t]
-        acc_ref[0, :, v_offs[t] : v_offs[t + 1]] += d
-    zsum_ref[0] = zsum_ref[0] * alpha + jnp.sum(p * vzero_t[None, :], axis=-1)
+        acc_ref[:, v_offs[t] : v_offs[t + 1]] += d
+    zsum_ref[...] = zsum_ref[...] * alpha + jnp.sum(
+        p * vzero_t, axis=-1, keepdims=True)
 
 
-def _fused_kernel(
+def _attention_kernel(
+    n_ref,
     *refs,
     nk: int,
     nv: int,
@@ -120,10 +125,12 @@ def _fused_kernel(
     pack: int,
     sm_scale: float,
     tile_l: int,
+    block_l: int,
 ):
     """refs layout: [k_payload*nk, k_mins*nk, k_shifts*nk, kscale, kzero,
-    v_payload*nv, v_mins*nv, v_shifts*nv, vscale, vzero, q, n_comp,
-    acc_out, zsum_out, m_out, l_out]."""
+    v_payload*nv, v_mins*nv, v_shifts*nv, vscale, vzero, q,
+    acc_out, zsum_out, m_out, l_out]; ``n_ref`` holds every row's
+    valid-token count (scalar prefetch)."""
     i = 0
     k_pay = refs[i : i + nk]; i += nk
     k_min = refs[i : i + nk]; i += nk
@@ -133,163 +140,119 @@ def _fused_kernel(
     v_min = refs[i : i + nv]; i += nv
     v_shf = refs[i : i + nv]; i += nv
     vscale_ref, vzero_ref = refs[i], refs[i + 1]; i += 2
-    q_ref, n_ref = refs[i], refs[i + 1]; i += 2
+    q_ref = refs[i]; i += 1
     acc_ref, zsum_ref, m_ref, l_ref = refs[i : i + 4]
 
-    pid = pl.program_id(1)
+    b, j = pl.program_id(0), pl.program_id(1)  # outside pl.when (interpret)
+    n_live = n_ref[b]
 
-    @pl.when(pid == 0)
+    @pl.when(j == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
         zsum_ref[...] = jnp.zeros_like(zsum_ref)
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    # tile skipping: a tile starting at/past this row's valid length is a
-    # flash no-op (scores all NEG_INF -> m_new = m_prev, alpha = 1, p = 0),
-    # so skip the K/V decode, both dot_generals and the softmax update
-    @pl.when(pid * tile_l < n_ref[0, 0])
-    def _live_tile():
-        k_tiles = [
-            decode_tier_tile(k_pay[t][0], k_min[t][0], k_shf[t][0],
-                             k_widths[t], pack)
-            for t in range(nk)
-        ]
-        v_tiles = [
-            decode_tier_tile(v_pay[t][0], v_min[t][0], v_shf[t][0],
-                             v_widths[t], pack)
-            for t in range(nv)
-        ]
-        _flash_tile_body(
-            q_ref[0], k_tiles, v_tiles,
-            kscale_ref[0], kzero_ref[0], vscale_ref[0], vzero_ref[0],
-            n_ref[0, 0], pid * tile_l + jnp.arange(tile_l),
-            acc_ref, zsum_ref, m_ref, l_ref,
-            k_offs=k_offs, v_offs=v_offs, sm_scale=sm_scale,
-        )
+    def tier_tiles(pays, mins, shfs, widths, s):
+        tiles = []
+        for t in range(len(pays)):
+            ws, ps, bs = tile_slices(s, tile_l, widths[t], pack)
+            tiles.append(decode_tier_tile(pays[t][:, ws], mins[t][:, ps],
+                                          shfs[t][:, bs], widths[t], pack))
+        return tiles
+
+    for s in range(block_l // tile_l):
+        start = j * block_l + s * tile_l
+        tok = slice(s * tile_l, (s + 1) * tile_l)
+
+        # tile skipping: a tile starting at/past this row's valid length is
+        # a flash no-op (scores all NEG_INF -> m_new = m_prev, alpha = 1,
+        # p = 0), so skip the K/V decode, both dot_generals and the update
+        @pl.when(start < n_live)
+        def _live_tile(s=s, start=start, tok=tok):
+            _flash_tile_body(
+                q_ref[...],
+                tier_tiles(k_pay, k_min, k_shf, k_widths, s),
+                tier_tiles(v_pay, v_min, v_shf, v_widths, s),
+                kscale_ref[:, tok], kzero_ref[:, tok],
+                vscale_ref[:, tok], vzero_ref[:, tok],
+                n_live, token_iota(start, tile_l),
+                acc_ref, zsum_ref, m_ref, l_ref,
+                k_offs=k_offs, v_offs=v_offs, sm_scale=sm_scale,
+            )
 
 
-def fused_packed_attention(
-    q: Array,
-    kc: TieredCache,
-    vc: TieredCache,
-    n_comp: Array,
-    sm_scale: float,
-    *,
-    tile_l: int = DEFAULT_TILE_L,
-    interpret: bool = True,
-):
-    """Compressed-region attention partials in ONE kernel launch.
+def _offsets(counts) -> tuple[int, ...]:
+    return (0, *[sum(counts[: i + 1]) for i in range(len(counts))])
 
-    q: f32 [B, H, D] in ORIGINAL channel order. n_comp: scalar or per-row
-    [B] valid lengths (continuous batching — each grid row masks to its own
-    count). Returns (o_unnorm [B,H,Dv] in original channel order, m [B,H],
-    l [B,H]) — log-sum-exp partials for merging with the residual buffer.
-    """
+
+def _launch(q, kc, vc, n_comp, sm_scale, *, tile_l, block_l, n_blocks,
+            leaf_spec, operands, metas, table=None):
+    """Shared launch of ``_attention_kernel``: ``leaf_spec`` blocks a tier
+    leaf, ``operands(tc)`` lists a cache's (payload, mins, shifts) leaves,
+    ``metas`` are the [BH, 1, n_tokens] (kscale, kzero, vscale, vzero);
+    ``table`` is the flat page table of a paged launch."""
     from ..core.tiered import chan_inverse_perm
 
     B, H, D = q.shape
-    h_kv = kc.scale.shape[-2]
+    h_kv = kc.chan_perm.shape[-2]
     G = H // h_kv
     BH = B * h_kv
-    L = kc.capacity
-    # bucketed launches can slice the cache below the default tile; clamp so
-    # a small live prefix lowers as a single (smaller) tile
-    tile_l = min(tile_l, L)
-    assert L % tile_l == 0 and tile_l % (kc.spec.pack_size * 4) == 0
-    nL = L // tile_l
     pack = kc.spec.pack_size
     Dv = vc.spec.head_dim
+    assert block_l % tile_l == 0 and tile_l % (pack * 4) == 0, (block_l, tile_l)
 
     # absorb the K channel permutation into q (free — paper §III-B3)
     qg = q.astype(jnp.float32).reshape(B, h_kv, G, D)
-    qp = jnp.take_along_axis(qg, kc.chan_perm[:, :, None, :], axis=-1)
+    qf = jnp.take_along_axis(qg, kc.chan_perm[:, :, None, :], axis=-1)
+    qf = qf.reshape(BH, G, D)
 
-    flat = lambda a: a.reshape(BH, *a.shape[2:])
-    k_pay = [flat(t.payload) for t in kc.tiers]
-    k_min = [flat(t.mins) for t in kc.tiers]
-    k_shf = [flat(t.shifts) for t in kc.tiers]
-    v_pay = [flat(t.payload) for t in vc.tiers]
-    v_min = [flat(t.mins) for t in vc.tiers]
-    v_shf = [flat(t.shifts) for t in vc.tiers]
-    kscale, kzero = flat(kc.scale), flat(kc.zero)
-    vscale, vzero = flat(vc.scale), flat(vc.zero)
-    qf = qp.reshape(BH, G, D)
-    # per-(batch,kv-head) valid length: [B] rows broadcast across heads
-    n_arr = jnp.asarray(n_comp, jnp.int32)
-    if n_arr.ndim == 0:
-        n_arr = n_arr[None, None]
-    else:
-        n_arr = n_arr[:, None]
-    n_arr = jnp.broadcast_to(n_arr, (B, h_kv)).reshape(BH, 1)
-
-    k_widths = tuple(t.width for t in kc.tiers)
-    v_widths = tuple(t.width for t in vc.tiers)
-    k_offs = (0, *[sum(kc.spec.counts[: i + 1]) for i in range(len(kc.spec.counts))])
-    v_offs = (0, *[sum(vc.spec.counts[: i + 1]) for i in range(len(vc.spec.counts))])
-
-    tP = tile_l // pack
-
-    def tier_specs(cs, widths):
-        sp = []
-        for c, w in zip(cs, widths):
-            sp.append(pl.BlockSpec((1, c, tile_l * w // 32), lambda b, l: (b, 0, l)))
-        for c in cs:
-            sp.append(pl.BlockSpec((1, c, tP), lambda b, l: (b, 0, l)))
-        for c in cs:
-            sp.append(pl.BlockSpec((1, c, tP // 4), lambda b, l: (b, 0, l)))
-        return sp
-
-    scale_spec = pl.BlockSpec((1, tile_l), lambda b, l: (b, l))
+    k_leaves, v_leaves = operands(kc), operands(vc)
+    meta_spec = row_block_spec(1, block_l)
     in_specs = (
-        tier_specs(kc.spec.counts, k_widths)
-        + [scale_spec, scale_spec]
-        + tier_specs(vc.spec.counts, v_widths)
-        + [scale_spec, scale_spec]
-        + [
-            pl.BlockSpec((1, G, D), lambda b, l: (b, 0, 0)),
-            pl.BlockSpec((1, 1), lambda b, l: (b, 0)),
-        ]
+        [leaf_spec(x) for x in k_leaves] + [meta_spec, meta_spec]
+        + [leaf_spec(x) for x in v_leaves] + [meta_spec, meta_spec]
+        + [row_spec((G, D))]
     )
-    out_specs = [
-        pl.BlockSpec((1, G, Dv), lambda b, l: (b, 0, 0)),
-        pl.BlockSpec((1, G), lambda b, l: (b, 0)),
-        pl.BlockSpec((1, G), lambda b, l: (b, 0)),
-        pl.BlockSpec((1, G), lambda b, l: (b, 0)),
-    ]
+    out_specs = [row_spec((G, Dv)), row_spec((G, 1)), row_spec((G, 1)),
+                 row_spec((G, 1))]
     out_shape = [
         jax.ShapeDtypeStruct((BH, G, Dv), jnp.float32),
-        jax.ShapeDtypeStruct((BH, G), jnp.float32),
-        jax.ShapeDtypeStruct((BH, G), jnp.float32),
-        jax.ShapeDtypeStruct((BH, G), jnp.float32),
+        jax.ShapeDtypeStruct((BH, G, 1), jnp.float32),
+        jax.ShapeDtypeStruct((BH, G, 1), jnp.float32),
+        jax.ShapeDtypeStruct((BH, G, 1), jnp.float32),
     ]
-
     kernel = functools.partial(
-        _fused_kernel,
+        _attention_kernel,
         nk=len(kc.tiers),
         nv=len(vc.tiers),
-        k_widths=k_widths,
-        v_widths=v_widths,
-        k_offs=k_offs,
-        v_offs=v_offs,
+        k_widths=tuple(t.width for t in kc.tiers),
+        v_widths=tuple(t.width for t in vc.tiers),
+        k_offs=_offsets(kc.spec.counts),
+        v_offs=_offsets(vc.spec.counts),
         pack=pack,
         sm_scale=sm_scale,
         tile_l=tile_l,
+        block_l=block_l,
     )
-    acc, zsum, m, lsum = pl.pallas_call(
+    scalars = [rows_valid(n_comp, B, h_kv)]
+    if table is not None:  # the page table is read by the index maps only
+        body = kernel
+        kernel = lambda n_ref, tab_ref, *refs: body(n_ref, *refs)
+        scalars.append(table)
+    kscale, kzero, vscale, vzero = metas
+    acc, zsum, m, lsum = packed_call(
         kernel,
-        grid=(BH, nL),
+        grid=(BH, n_blocks),
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
-        interpret=interpret,
-        **tpu_params(("parallel", "arbitrary"), interpret),
-    )(
-        *k_pay, *k_min, *k_shf, kscale, kzero,
-        *v_pay, *v_min, *v_shf, vscale, vzero, qf, n_arr,
+        scalars=scalars,
+        operands=[*k_leaves, kscale, kzero, *v_leaves, vscale, vzero, qf],
+        dimension_semantics=("parallel", "arbitrary"),
     )
 
-    o = acc + zsum[..., None]  # zero-term correction (all channels)
+    o = acc + zsum  # zero-term correction (all channels)
     o = o.reshape(B, h_kv, G, Dv)
     inv = chan_inverse_perm(vc.chan_perm)
     o = jnp.take_along_axis(o, inv[:, :, None, :], axis=-1)
@@ -300,76 +263,41 @@ def fused_packed_attention(
     )
 
 
-# ---------------------------------------------------------------------------
-# Paged variant: context tiles resolved through the page table in-kernel
-# ---------------------------------------------------------------------------
+def _tier_leaves(tc: TieredCache):
+    return ([t.payload for t in tc.tiers] + [t.mins for t in tc.tiers]
+            + [t.shifts for t in tc.tiers])
 
 
-def _paged_fused_kernel(
-    *refs,
-    nk: int,
-    nv: int,
-    k_widths,
-    v_widths,
-    k_offs,
-    v_offs,
-    pack: int,
+def fused_packed_attention(
+    q: Array,
+    kc: TieredCache,
+    vc: TieredCache,
+    n_comp: Array,
     sm_scale: float,
-    tile_l: int,
-    tiles_per_page: int,
+    *,
+    tile_l: int = DEFAULT_TILE_L,
 ):
-    """refs layout: [k_payload*nk, k_mins*nk, k_shifts*nk, kscale, kzero,
-    v_payload*nv, v_mins*nv, v_shifts*nv, vscale, vzero, q, n_comp, table,
-    acc_out, zsum_out, m_out, l_out]. Pool refs are whole-pool blocks of one
-    kv-head; scale/zero are pre-gathered dense tiles; ``table`` is this
-    row's page-table prefix."""
-    i = 0
-    k_pay = refs[i : i + nk]; i += nk
-    k_min = refs[i : i + nk]; i += nk
-    k_shf = refs[i : i + nk]; i += nk
-    kscale_ref, kzero_ref = refs[i], refs[i + 1]; i += 2
-    v_pay = refs[i : i + nv]; i += nv
-    v_min = refs[i : i + nv]; i += nv
-    v_shf = refs[i : i + nv]; i += nv
-    vscale_ref, vzero_ref = refs[i], refs[i + 1]; i += 2
-    q_ref, n_ref, tab_ref = refs[i], refs[i + 1], refs[i + 2]; i += 3
-    acc_ref, zsum_ref, m_ref, l_ref = refs[i : i + 4]
+    """Compressed-region attention partials in ONE kernel launch.
 
-    pid = pl.program_id(1)  # outside pl.when (interpret mode)
-    lp = pid // tiles_per_page  # logical page of this tile
-    toff = pid % tiles_per_page  # tile within the page
-
-    @pl.when(pid == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        zsum_ref[...] = jnp.zeros_like(zsum_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-
-    # same tile skipping as the dense kernel — a dead tile never even
-    # resolves its page id
-    @pl.when(pid * tile_l < n_ref[0, 0])
-    def _live_tile():
-        phys = load_page_id(tab_ref, lp)
-
-        def tier_tiles(pays, mins, shfs, widths):
-            return [
-                decode_tier_tile(
-                    *load_tier_pool_tile(pays[t], mins[t], shfs[t], phys,
-                                         toff, tile_l, widths[t], pack),
-                    widths[t], pack,
-                )
-                for t in range(len(pays))
-            ]
-
-        _flash_tile_body(
-            q_ref[0], tier_tiles(k_pay, k_min, k_shf, k_widths),
-            tier_tiles(v_pay, v_min, v_shf, v_widths),
-            kscale_ref[0], kzero_ref[0], vscale_ref[0], vzero_ref[0],
-            n_ref[0, 0], pid * tile_l + jnp.arange(tile_l),
-            acc_ref, zsum_ref, m_ref, l_ref,
-            k_offs=k_offs, v_offs=v_offs, sm_scale=sm_scale,
-        )
+    q: f32 [B, H, D] in ORIGINAL channel order. n_comp: scalar or per-row
+    [B] valid lengths (continuous batching — each grid row masks to its own
+    count). Returns (o_unnorm [B,H,Dv] in original channel order, m [B,H],
+    l [B,H]) — log-sum-exp partials for merging with the residual buffer.
+    """
+    B = q.shape[0]
+    h_kv = kc.scale.shape[-2]
+    L = kc.capacity
+    # bucketed launches can slice the cache below the default tile; clamp so
+    # a small live prefix lowers as a single (smaller) tile
+    tile_l = min(tile_l, L)
+    flat = lambda a: a.reshape(B * h_kv, *a.shape[2:])
+    meta = lambda a: a.reshape(B * h_kv, 1, L)
+    return _launch(
+        q, kc, vc, n_comp, sm_scale, tile_l=tile_l, block_l=L, n_blocks=1,
+        leaf_spec=lambda x: row_spec(x.shape[1:]),
+        operands=lambda tc: [flat(x) for x in _tier_leaves(tc)],
+        metas=[meta(kc.scale), meta(kc.zero), meta(vc.scale), meta(vc.zero)],
+    )
 
 
 def fused_packed_attention_paged(
@@ -383,125 +311,31 @@ def fused_packed_attention_paged(
     *,
     page_size: int,
     tile_l: int = DEFAULT_TILE_L,
-    interpret: bool = True,
 ):
     """Compressed-region attention partials over a PAGED cache in one launch.
 
     kc/vc: pool-layout TieredCaches (leaves [H_kv, n_pool_pages, ...]);
     page_table: i32 [B, max_pages]; n_tokens: STATIC bucket size (multiple
-    of ``page_size``) — the grid covers ``n_tokens / tile_l`` logical tiles
-    and each live tile resolves its physical page through the table.
+    of ``page_size``) — the grid covers ``n_tokens / page_size`` logical
+    pages and each step's block is the physical page the table names.
     Returns the same (o_unnorm, m, l) partials as ``fused_packed_attention``
     and is bit-identical to running it on the gathered dense view.
     """
-    from ..core.tiered import chan_inverse_perm, gather_pool_leaf
+    from ..core.tiered import gather_page_meta
 
-    B, H, D = q.shape
+    B = q.shape[0]
     h_kv = kc.scale.shape[0]
-    G = H // h_kv
-    BH = B * h_kv
     tile_l = min(tile_l, page_size)
-    assert page_size % tile_l == 0 and tile_l % (kc.spec.pack_size * 4) == 0
     assert n_tokens % page_size == 0, (n_tokens, page_size)
     n_pg = n_tokens // page_size
-    tpp = page_size // tile_l
-    nL = n_pg * tpp
-    pack = kc.spec.pack_size
-    Dv = vc.spec.head_dim
-
-    qg = q.astype(jnp.float32).reshape(B, h_kv, G, D)
-    qp = jnp.take_along_axis(qg, kc.chan_perm[:, :, None, :], axis=-1)
-    qf = qp.reshape(BH, G, D)
-
-    idx = page_table[:, :n_pg]  # [B, n_pg] live logical pages
     # per-token metadata is rank-1 and bucket-sized: gather it dense outside
-    flatm = lambda a: gather_pool_leaf(a, idx).reshape(BH, n_tokens)
-    kscale, kzero = flatm(kc.scale), flatm(kc.zero)
-    vscale, vzero = flatm(vc.scale), flatm(vc.zero)
-
-    n_arr = jnp.asarray(n_comp, jnp.int32)
-    if n_arr.ndim == 0:
-        n_arr = n_arr[None, None]
-    else:
-        n_arr = n_arr[:, None]
-    n_arr = jnp.broadcast_to(n_arr, (B, h_kv)).reshape(BH, 1)
-
-    k_widths = tuple(t.width for t in kc.tiers)
-    v_widths = tuple(t.width for t in vc.tiers)
-    k_offs = (0, *[sum(kc.spec.counts[: i + 1]) for i in range(len(kc.spec.counts))])
-    v_offs = (0, *[sum(vc.spec.counts[: i + 1]) for i in range(len(vc.spec.counts))])
-
-    def pool_specs(tiers):
-        # whole-pool blocks of this grid row's kv-head (see module docstring
-        # for the TPU scalar-prefetch caveat)
-        return [
-            pool_block_spec(getattr(t, leaf), h_kv)
-            for leaf in ("payload", "mins", "shifts")
-            for t in tiers
-        ]
-
-    scale_spec = pl.BlockSpec((1, tile_l), lambda b, l: (b, l))
-    in_specs = (
-        pool_specs(kc.tiers)
-        + [scale_spec, scale_spec]
-        + pool_specs(vc.tiers)
-        + [scale_spec, scale_spec]
-        + [
-            pl.BlockSpec((1, G, D), lambda b, l: (b, 0, 0)),
-            pl.BlockSpec((1, 1), lambda b, l: (b, 0)),
-            page_table_spec(n_pg, h_kv),
-        ]
-    )
-    out_specs = [
-        pl.BlockSpec((1, G, Dv), lambda b, l: (b, 0, 0)),
-        pl.BlockSpec((1, G), lambda b, l: (b, 0)),
-        pl.BlockSpec((1, G), lambda b, l: (b, 0)),
-        pl.BlockSpec((1, G), lambda b, l: (b, 0)),
-    ]
-    out_shape = [
-        jax.ShapeDtypeStruct((BH, G, Dv), jnp.float32),
-        jax.ShapeDtypeStruct((BH, G), jnp.float32),
-        jax.ShapeDtypeStruct((BH, G), jnp.float32),
-        jax.ShapeDtypeStruct((BH, G), jnp.float32),
-    ]
-
-    kernel = functools.partial(
-        _paged_fused_kernel,
-        nk=len(kc.tiers),
-        nv=len(vc.tiers),
-        k_widths=k_widths,
-        v_widths=v_widths,
-        k_offs=k_offs,
-        v_offs=v_offs,
-        pack=pack,
-        sm_scale=sm_scale,
-        tile_l=tile_l,
-        tiles_per_page=tpp,
-    )
-    pool_leaves = lambda tc: (
-        [t.payload for t in tc.tiers]
-        + [t.mins for t in tc.tiers]
-        + [t.shifts for t in tc.tiers]
-    )
-    acc, zsum, m, lsum = pl.pallas_call(
-        kernel,
-        grid=(BH, nL),
-        in_specs=in_specs,
-        out_specs=out_specs,
-        out_shape=out_shape,
-        interpret=interpret,
-        **tpu_params(("parallel", "arbitrary"), interpret),
-    )(
-        *pool_leaves(kc), kscale, kzero,
-        *pool_leaves(vc), vscale, vzero, qf, n_arr, idx,
-    )
-
-    o = acc + zsum[..., None]
-    o = o.reshape(B, h_kv, G, Dv)
-    inv = chan_inverse_perm(vc.chan_perm)
-    o = jnp.take_along_axis(o, inv[:, :, None, :], axis=-1)
-    return (
-        o.reshape(B, H, Dv),
-        m.reshape(B, H),
-        lsum.reshape(B, H),
+    meta = lambda a: gather_page_meta(
+        a, page_table, n_tokens, page_size).reshape(B * h_kv, 1, n_tokens)
+    return _launch(
+        q, kc, vc, n_comp, sm_scale, tile_l=tile_l, block_l=page_size,
+        n_blocks=n_pg,
+        leaf_spec=lambda x: pool_page_spec(x, h_kv, n_pg, page_size),
+        operands=_tier_leaves,
+        metas=[meta(kc.scale), meta(kc.zero), meta(vc.scale), meta(vc.zero)],
+        table=page_table[:, :n_pg].reshape(-1).astype(jnp.int32),
     )

@@ -1,78 +1,126 @@
-"""Shared Pallas plumbing (TPU compiler params with interpret fallback,
-page-pool tile loads for the paged kernels)."""
+"""Shared Pallas plumbing: the platform's interpret/compile choice, TPU
+compiler params, and the block specs of the dense and paged cache layouts.
+
+Every packed kernel runs on a ``(B·H_kv, n_blocks)`` grid whose step
+``(b, j)`` sees one BLOCK of ``block_l`` context tokens of row ``b``:
+
+* dense layout — the block is the row's whole token axis (``n_blocks`` = 1);
+* paged layout — the block is one physical page, resolved through the page
+  table in the block index map (scalar prefetch), so only the addressed page
+  is DMA'd into VMEM.
+
+Either way the block's last two dims equal the array's (``[C_t, words]``),
+which is what Mosaic requires of blocks narrower than 128 lanes: a 2-bit
+tier's 256-token tile is only 16 words wide. Kernels then walk the block in
+``tile_l``-token compute tiles with static lane slices (``tile_slices``).
+"""
 from __future__ import annotations
 
+import jax
+import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+VMEM_LIMIT_CAP = 96 * 2**20  # v5e has 128 MiB of VMEM per core
+VMEM_HEADROOM = 16 * 2**20  # decoded tiles and spills beyond the blocks
 
 
-def load_page_id(table_ref, lp):
-    """Resolve logical page ``lp`` (traced) through a [1, n_pages] page-table
-    block: the in-kernel half of the page-table indirection."""
-    return pl.load(table_ref, (pl.dslice(0, 1), pl.dslice(lp, 1)))[0, 0]
+def interpret_mode() -> bool:
+    """The one place the platform picks how Pallas kernels run: in the
+    interpreter on CPU, compiled by Mosaic on TPU."""
+    platform = jax.default_backend()
+    if platform == "cpu":
+        return True
+    if platform == "tpu":
+        return False
+    raise RuntimeError(
+        f"packed Pallas kernels run compiled on TPU or interpreted on CPU; "
+        f"the default backend is {platform!r}")
 
 
-def load_pool_tile(ref, phys, start, size):
-    """Dynamic tile load from a whole-pool ref.
+def _padded_bytes(shape, dtype) -> int:
+    """VMEM bytes of one block: the last two dims pad to the (sublane,
+    128-lane) tile, sublanes packing 32 bits (8 rows of f32, 32 of int8)."""
+    itemsize = np.dtype(dtype).itemsize
+    shape = tuple(d for d in shape if d is not None)
+    rows, lanes = (1, *shape)[-2:]
+    sub = 8 * 4 // itemsize
+    rows = -(-rows // sub) * sub
+    lanes = -(-lanes // 128) * 128
+    return int(np.prod(shape[:-2], dtype=np.int64)) * rows * lanes * itemsize
 
-    ref: [1, n_pool_pages, C, U] block (one kv-head's pool); phys: traced
-    physical page id; start/size: element window on the last axis. Returns
-    [C, size]. ``pl.dslice`` keeps every index a Slice, which both the
-    interpret-mode discharge rule and the TPU lowering accept.
-    """
-    C = ref.shape[2]
-    tile = pl.load(
-        ref,
-        (pl.dslice(0, 1), pl.dslice(phys, 1), pl.dslice(0, C),
-         pl.dslice(start, size)),
+
+def packed_call(kernel, *, grid, in_specs, out_specs, out_shape, scalars,
+                operands, dimension_semantics):
+    """``pallas_call`` of a packed kernel: ``scalars`` ride in SMEM as scalar
+    prefetch (index maps and kernel body both read them), ``operands`` are
+    blocked by ``in_specs``. The VMEM limit is the double-buffered blocks
+    plus ``VMEM_HEADROOM``."""
+    listed = lambda x: list(x) if isinstance(x, (list, tuple)) else [x]
+    blocks = sum(
+        _padded_bytes(s.block_shape, x.dtype)
+        for s, x in zip(in_specs + listed(out_specs),
+                        list(operands) + listed(out_shape))
     )
-    return tile.reshape(C, size)
+    vmem = min(2 * blocks + VMEM_HEADROOM, VMEM_LIMIT_CAP)
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(scalars), grid=grid,
+            in_specs=in_specs, out_specs=out_specs),
+        out_shape=out_shape,
+        interpret=interpret_mode(),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=dimension_semantics, vmem_limit_bytes=vmem),
+    )(*scalars, *operands)
 
 
-def load_tier_pool_tile(payload_ref, mins_ref, shifts_ref, phys, toff,
-                        tile_l, width, pack):
-    """Load one tier's (payload, mins, shifts) tile from whole-pool refs.
-
-    ``phys``: traced physical page id; ``toff``: tile index within the
-    page. The offset triple (words / packs / shift bytes per tile) is THE
-    pool-layout contract (docs/formats.md) — keep every paged kernel on
-    this helper so a layout change lands in one place.
-    """
-    return (
-        load_pool_tile(payload_ref, phys, toff * (tile_l * width // 32),
-                       tile_l * width // 32),
-        load_pool_tile(mins_ref, phys, toff * (tile_l // pack),
-                       tile_l // pack),
-        load_pool_tile(shifts_ref, phys, toff * (tile_l // pack // 4),
-                       tile_l // pack // 4),
-    )
+def rows_valid(n, B: int, h_kv: int):
+    """Scalar or per-row [B] valid-token counts -> i32 [B·H_kv], the
+    kernels' batch-major row layout."""
+    n = jnp.asarray(n, jnp.int32)
+    n = n[None] if n.ndim == 0 else n
+    return jnp.broadcast_to(n[:, None], (B, h_kv)).reshape(B * h_kv)
 
 
-def pool_block_spec(leaf, h_kv: int):
-    """BlockSpec handing a paged kernel ONE kv-head's whole pool.
-
-    Grid dim 0 indexes (batch, kv-head) pairs batch-major, so the head is
-    ``b % h_kv``. The other half of the pool-layout contract
-    (``load_tier_pool_tile``) lives below — a layout change (e.g. moving
-    the page table to scalar prefetch on real TPU) edits this module only.
-    """
-    return pl.BlockSpec((1, *leaf.shape[1:]), lambda b, l: (b % h_kv, 0, 0, 0))
+def row_spec(block_shape):
+    """Dense layout: grid row ``b`` takes its whole ``[b, ...]`` slice."""
+    zeros = (0,) * len(block_shape)
+    return pl.BlockSpec((None, *block_shape), lambda b, j, *_: (b, *zeros))
 
 
-def page_table_spec(n_pages: int, h_kv: int):
-    """BlockSpec handing a paged kernel its row's live page-table prefix."""
-    return pl.BlockSpec((1, n_pages), lambda b, l: (b // h_kv, 0))
+def row_block_spec(rows: int, block_l: int):
+    """``[BH, rows, n_tokens]`` operands (per-token metadata, weights,
+    kpack scores): grid step ``(b, j)`` takes token block ``j`` of row ``b``."""
+    return pl.BlockSpec((None, rows, block_l), lambda b, j, *_: (b, 0, j))
 
 
-def tpu_params(dimension_semantics: tuple[str, ...], interpret: bool) -> dict:
-    """CompilerParams for TPU lowering; empty under interpret mode."""
-    if interpret:
-        return {}
-    try:
-        from jax.experimental.pallas import tpu as pltpu
+def pool_page_spec(leaf, h_kv: int, n_pg: int, page_size: int):
+    """Paged layout: grid step ``(b, j)`` takes logical page ``j`` of its
+    row, i.e. physical page ``table[row, j]`` of kv-head ``b % h_kv``
+    (leaf ``[H_kv, n_pool_pages, C, units]``). Pages past the row's last
+    live one re-address that page, so dead steps fetch nothing new."""
 
-        cp = getattr(pltpu, "CompilerParams", None) or getattr(
-            pltpu, "TPUCompilerParams"
-        )
-        return {"compiler_params": cp(dimension_semantics=dimension_semantics)}
-    except Exception:  # pragma: no cover - non-TPU build
-        return {}
+    def index(b, j, n_ref, tab_ref):
+        last = jnp.maximum(n_ref[b] - 1, 0) // page_size
+        return (b % h_kv, tab_ref[(b // h_kv) * n_pg + jnp.minimum(j, last)],
+                0, 0)
+
+    return pl.BlockSpec((None, None, *leaf.shape[2:]), index)
+
+
+def tile_slices(s: int, tile_l: int, width: int, pack: int):
+    """Static lane slices of compute tile ``s`` within a block, for the
+    (payload, mins, shifts) leaves of a ``width``-bit tier. The offset
+    triple (words / packs / shift bytes per tile) is THE token-axis layout
+    contract (docs/formats.md)."""
+    w, p, b = tile_l * width // 32, tile_l // pack, tile_l // pack // 4
+    return slice(s * w, (s + 1) * w), slice(s * p, (s + 1) * p), \
+        slice(s * b, (s + 1) * b)
+
+
+def token_iota(start, tile_l: int):
+    """Global token indices ``[1, tile_l]`` of a tile (2-D: Mosaic has no
+    1-D iota)."""
+    return start + jax.lax.broadcasted_iota(jnp.int32, (1, tile_l), 1)

@@ -38,6 +38,13 @@ Array = jax.Array
 NEG_INF = -1e30
 
 
+def einsum(spec: str, *operands: Array) -> Array:
+    """``jnp.einsum`` at f32 precision. On TPU, XLA's default would round
+    f32 operands to bf16 for one MXU pass; the Pallas kernels contract in
+    f32, and the oracle must compute what they compute."""
+    return jnp.einsum(spec, *operands, precision=jax.lax.Precision.HIGHEST)
+
+
 def valid_mask(n: Array, length: int, lead: int) -> Array:
     """``arange(length) < n`` with ``lead`` broadcast axes before the length.
 
@@ -77,7 +84,7 @@ def kpack_scores_ref(q: Array, kc: TieredCache, sm_scale: float = 1.0) -> Array:
     off = 0
     for t, c in zip(kc.tiers, kc.spec.counts):
         qint = unpack_tier(t, L).astype(jnp.float32)  # [B, Hkv, C_t, L]
-        si = si + jnp.einsum("bhgc,bhcl->bhgl", qp[..., off : off + c], qint)
+        si = si + einsum("bhgc,bhcl->bhgl", qp[..., off : off + c], qint)
         off += c
     qsum = jnp.sum(qg, axis=-1, keepdims=True)  # [B, Hkv, G, 1]
     scores = si * kc.scale[:, :, None, :] + qsum * kc.zero[:, :, None, :]
@@ -97,9 +104,9 @@ def vpack_out_ref(w: Array, vc: TieredCache) -> Array:
     parts = []
     for t in vc.tiers:
         qint = unpack_tier(t, L).astype(jnp.float32)  # [B, Hkv, C_t, L]
-        parts.append(jnp.einsum("bhgl,bhcl->bhgc", ws, qint))
+        parts.append(einsum("bhgl,bhcl->bhgc", ws, qint))
     out = jnp.concatenate(parts, axis=-1)  # tier channel order
-    zterm = jnp.einsum("bhgl,bhl->bhg", wg, vc.zero)[..., None]
+    zterm = einsum("bhgl,bhl->bhg", wg, vc.zero)[..., None]
     out = out + zterm
     inv = chan_inverse_perm(vc.chan_perm)  # undo channel permutation
     out = jnp.take_along_axis(out, inv[:, :, None, :], axis=-1)
@@ -132,7 +139,7 @@ def packed_decode_attention_ref(
     s_comp = jnp.where(mask_c, s_comp, NEG_INF)
 
     qg = _grouped_q(q.astype(jnp.float32), h_kv)
-    s_res = jnp.einsum(
+    s_res = einsum(
         "bhgd,bhrd->bhgr", qg, resid_k.astype(jnp.float32)
     ).reshape(B, H, R) * sm_scale
     mask_r = valid_mask(n_resid, R, lead=2)
@@ -148,7 +155,7 @@ def packed_decode_attention_ref(
 
     o_comp = vpack_out_ref(w_comp, vc)  # [B, H, D] (unnormalized)
     wg = w_res.reshape(B, h_kv, H // h_kv, R)
-    o_res = jnp.einsum("bhgr,bhrd->bhgd", wg, resid_v.astype(jnp.float32)).reshape(B, H, D)
+    o_res = einsum("bhgr,bhrd->bhgd", wg, resid_v.astype(jnp.float32)).reshape(B, H, D)
     return (o_comp + o_res) / jnp.maximum(denom, 1e-30)
 
 
@@ -170,8 +177,8 @@ def dense_decode_attention_ref(
     h_kv = raw_k.shape[1]
     L, R = raw_k.shape[2], resid_k.shape[2]
     qg = _grouped_q(q.astype(jnp.float32), h_kv)
-    s_c = jnp.einsum("bhgd,bhld->bhgl", qg, raw_k.astype(jnp.float32)) * sm_scale
-    s_r = jnp.einsum("bhgd,bhrd->bhgr", qg, resid_k.astype(jnp.float32)) * sm_scale
+    s_c = einsum("bhgd,bhld->bhgl", qg, raw_k.astype(jnp.float32)) * sm_scale
+    s_r = einsum("bhgd,bhrd->bhgr", qg, resid_k.astype(jnp.float32)) * sm_scale
     mask_c = valid_mask(n_comp, L, lead=3)
     mask_r = valid_mask(n_resid, R, lead=3)
     s_c = jnp.where(mask_c, s_c, NEG_INF)
@@ -180,7 +187,7 @@ def dense_decode_attention_ref(
     w_c = jnp.where(mask_c, jnp.exp(s_c - m), 0.0)
     w_r = jnp.where(mask_r, jnp.exp(s_r - m), 0.0)
     denom = w_c.sum(-1, keepdims=True) + w_r.sum(-1, keepdims=True)
-    o = jnp.einsum("bhgl,bhld->bhgd", w_c, raw_v.astype(jnp.float32)) + jnp.einsum(
+    o = einsum("bhgl,bhld->bhgd", w_c, raw_v.astype(jnp.float32)) + einsum(
         "bhgr,bhrd->bhgd", w_r, resid_v.astype(jnp.float32)
     )
     return (o / jnp.maximum(denom, 1e-30)).reshape(B, H, D)

@@ -4,38 +4,28 @@ These run inside ``pl.pallas_call`` bodies: everything is static-shape,
 uses only vectorizable integer ops (shift/mask/broadcast/reshape), and the
 decoded values live purely in VMEM/VREGs — the TPU analogue of the paper's
 "decompress into registers" (§III-C).
+
+Every packed field is first repeated out to token granularity along the
+lanes (``repeat_lanes``), then its bits are selected per lane from the
+token index. Mosaic lowers that one repeat pattern; it refuses the
+alternative of splitting words into a trailing axis of 4 shift fields and
+merging it back into the lanes.
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 
-def unpack_words_2d(words, width: int):
-    """u32 [C, Wl] -> i32 [C, Wl * (32//width)] stored values."""
-    assert width >= 1 and 32 % width == 0
-    vpw = 32 // width
-    offs = (jnp.arange(vpw, dtype=jnp.uint32) * width).astype(jnp.uint32)
-    mask = jnp.uint32(2**width - 1)
-    vals = (words[:, :, None] >> offs[None, None, :]) & mask
-    C, Wl = words.shape
-    return vals.reshape(C, Wl * vpw).astype(jnp.int32)
+def repeat_lanes(x, r: int):
+    """[C, n] -> [C, n * r], each column repeated ``r`` times."""
+    C, n = x.shape
+    return jnp.broadcast_to(x[:, :, None], (C, n, r)).reshape(C, n * r)
 
 
-def unpack_shifts_2d(shift_bytes, n_packs: int):
-    """u8 [C, ceil(P/4)] -> i32 [C, P] 2-bit shift fields."""
-    sb = shift_bytes.astype(jnp.int32)
-    offs = jnp.arange(4, dtype=jnp.int32) * 2
-    sh = (sb[:, :, None] >> offs[None, None, :]) & 3
-    C = sb.shape[0]
-    return sh.reshape(C, sb.shape[1] * 4)[:, :n_packs]
-
-
-def broadcast_packwise(per_pack, pack_size: int):
-    """[C, P] -> [C, P*pack_size] repeating each pack value."""
-    C, P = per_pack.shape
-    return jnp.broadcast_to(per_pack[:, :, None], (C, P, pack_size)).reshape(
-        C, P * pack_size
-    )
+def _log2(n: int) -> int:
+    assert n > 0 and n & (n - 1) == 0, f"{n} must be a power of two"
+    return n.bit_length() - 1
 
 
 def decode_tier_tile(payload, mins, shift_bytes, width: int, pack_size: int):
@@ -43,16 +33,23 @@ def decode_tier_tile(payload, mins, shift_bytes, width: int, pack_size: int):
 
     payload:     u32 [C, TL*width/32]
     mins:        i8  [C, TL/pack_size]
-    shift_bytes: u8  [C, ceil(TL/pack_size/4)]
+    shift_bytes: u8  [C, TL/pack_size/4]
     Returns f32 [C, TL] decoded quantized integers (mid-rise reconstruction
     of shift-dropped low bits), ready for the integer matvec.
     """
-    stored = unpack_words_2d(payload, width)  # [C, TL]
-    TL = stored.shape[1]
-    P = TL // pack_size
-    sh = unpack_shifts_2d(shift_bytes, P)  # [C, P]
-    sh_t = broadcast_packwise(sh, pack_size)  # [C, TL]
-    mins_t = broadcast_packwise(mins.astype(jnp.int32), pack_size)
+    assert width >= 1 and 32 % width == 0
+    vpw = 32 // width
+    TL = payload.shape[1] * vpw
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, TL), 1)
+    # token l: bits [(l % vpw)·w, +w) of word l // vpw
+    bit = ((lane & (vpw - 1)) * width).astype(jnp.uint32)
+    stored = ((repeat_lanes(payload, vpw) >> bit)
+              & jnp.uint32(2**width - 1)).astype(jnp.int32)
+    # pack p = l // pack: bits [2·(p % 4), +2) of shift byte p // 4
+    field = ((lane >> _log2(pack_size)) & 3) * 2
+    sh_t = (repeat_lanes(shift_bytes.astype(jnp.int32), 4 * pack_size)
+            >> field) & 3
+    mins_t = repeat_lanes(mins.astype(jnp.int32), pack_size)
     half = jnp.where(sh_t > 0, 1 << jnp.maximum(sh_t - 1, 0), 0)
     q = (stored << sh_t) + half + mins_t
     return q.astype(jnp.float32)

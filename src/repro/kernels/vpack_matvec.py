@@ -3,8 +3,9 @@
 Dot products run along the context dimension — the same direction V is
 bit-packed — so each decoded [C_t, TL] tile contracts immediately against
 the [G, TL] weight tile. The paper's fp32 ``atomicAdd`` partial sums become
-sequential accumulation over the context grid dimension into the output
-block (deterministic; grid dim 1 is "arbitrary" = sequential on TPU).
+sequential accumulation over the context tiles into the output block
+(deterministic; grid dim 1 is "arbitrary" = sequential on TPU). Blocks as
+in ``pallas_utils`` (a dense row or one paged page).
 """
 from __future__ import annotations
 
@@ -15,11 +16,13 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from .pallas_utils import (
-    load_page_id,
-    load_tier_pool_tile,
-    page_table_spec,
-    pool_block_spec,
-    tpu_params,
+    packed_call,
+    pool_page_spec,
+    row_block_spec,
+    row_spec,
+    rows_valid,
+    tile_slices,
+    token_iota,
 )
 from .unpack import decode_tier_tile
 
@@ -28,37 +31,32 @@ Array = jax.Array
 DEFAULT_TILE_L = 256
 
 
-def _kernel(*refs, width, pack, masked, tile_l):
-    if masked:
-        payload_ref, mins_ref, shifts_ref, w_ref, n_ref, out_ref = refs
-    else:
-        payload_ref, mins_ref, shifts_ref, w_ref, out_ref = refs
-        n_ref = None
+def _kernel(n_ref, payload_ref, mins_ref, shifts_ref, w_ref, out_ref, *,
+            width, pack, tile_l, block_l):
+    b, j = pl.program_id(0), pl.program_id(1)  # outside pl.when (interpret)
+    n_live = n_ref[b]
 
-    @pl.when(pl.program_id(1) == 0)
+    @pl.when(j == 0)
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    tile_start = pl.program_id(1) * tile_l  # outside pl.when (interpret mode)
+    for s in range(block_l // tile_l):
+        start = j * block_l + s * tile_l
 
-    def accumulate():
-        vals = decode_tier_tile(
-            payload_ref[0], mins_ref[0], shifts_ref[0], width, pack
-        )  # [C, TL]
-        w = w_ref[0]  # [G, TL]
-        if n_ref is not None:
-            gidx = tile_start + jnp.arange(tile_l)
-            w = jnp.where((gidx < n_ref[0, 0])[None, :], w, 0.0)
-        out_ref[0] += jax.lax.dot_general(
-            w, vals, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
-
-    if n_ref is None:
-        accumulate()
-    else:
         # tile skipping: a fully-masked tile accumulates exactly zero — skip
-        # the decode and both dot_generals (init above still runs at tile 0)
-        pl.when(tile_start < n_ref[0, 0])(accumulate)
+        # the decode and the dot_general (the init above still runs)
+        @pl.when(start < n_live)
+        def _accumulate(s=s, start=start):
+            ws, ps, bs = tile_slices(s, tile_l, width, pack)
+            vals = decode_tier_tile(payload_ref[:, ws], mins_ref[:, ps],
+                                    shifts_ref[:, bs], width, pack)  # [C, TL]
+            w = w_ref[:, s * tile_l : (s + 1) * tile_l]  # [G, TL]
+            w = jnp.where(token_iota(start, tile_l) < n_live, w, 0.0)
+            out_ref[...] += jax.lax.dot_general(
+                w, vals, (((1,), (1,)), ((), ())),
+                precision=jax.lax.Precision.HIGHEST,
+                preferred_element_type=jnp.float32,
+            )
 
 
 def vpack_tier_out(
@@ -71,7 +69,6 @@ def vpack_tier_out(
     pack_size: int,
     n_valid: Array | None = None,
     tile_l: int = DEFAULT_TILE_L,
-    interpret: bool = True,
 ) -> Array:
     """One tier's weighted-V output (tier channel order, scale pre-folded).
 
@@ -85,61 +82,19 @@ def vpack_tier_out(
     L = Wl * (32 // width)
     tile_l = min(tile_l, L)  # bucketed launches may slice below the tile
     assert L % tile_l == 0 and tile_l % (pack_size * 4) == 0
-    nL = L // tile_l
-    tWl = tile_l * width // 32
-    tP = tile_l // pack_size
-
-    in_specs = [
-        pl.BlockSpec((1, C, tWl), lambda b, l: (b, 0, l)),
-        pl.BlockSpec((1, C, tP), lambda b, l: (b, 0, l)),
-        pl.BlockSpec((1, C, tP // 4), lambda b, l: (b, 0, l)),
-        pl.BlockSpec((1, G, tile_l), lambda b, l: (b, 0, l)),
-    ]
-    args = [payload, mins, shifts, w]
-    if n_valid is not None:
-        in_specs.append(pl.BlockSpec((1, 1), lambda b, l: (b, 0)))
-        args.append(n_valid.astype(jnp.int32).reshape(BH, 1))
-
-    return pl.pallas_call(
+    nv = rows_valid(L if n_valid is None else n_valid, BH, 1)
+    return packed_call(
         functools.partial(_kernel, width=width, pack=pack_size,
-                          masked=n_valid is not None, tile_l=tile_l),
-        grid=(BH, nL),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, G, C), lambda b, l: (b, 0, 0)),
+                          tile_l=tile_l, block_l=L),
+        grid=(BH, 1),
+        in_specs=[row_spec(payload.shape[1:]), row_spec(mins.shape[1:]),
+                  row_spec(shifts.shape[1:]), row_block_spec(G, L)],
+        out_specs=row_spec((G, C)),
         out_shape=jax.ShapeDtypeStruct((BH, G, C), jnp.float32),
-        interpret=interpret,
-        **tpu_params(("parallel", "arbitrary"), interpret),
-    )(*args)
-
-
-def _paged_kernel(payload_ref, mins_ref, shifts_ref, w_ref, n_ref, tab_ref,
-                  out_ref, *, width, pack, tile_l, tiles_per_page):
-    """Paged weighted-V: page-table tile resolution + sequential
-    accumulation (see packed_attention.py for the interpret-mode caveat)."""
-    pid = pl.program_id(1)  # outside pl.when (interpret mode)
-    tile_start = pid * tile_l
-    lp = pid // tiles_per_page
-    toff = pid % tiles_per_page
-
-    @pl.when(pid == 0)
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    def accumulate():
-        phys = load_page_id(tab_ref, lp)
-        vals = decode_tier_tile(
-            *load_tier_pool_tile(payload_ref, mins_ref, shifts_ref, phys,
-                                 toff, tile_l, width, pack),
-            width, pack,
-        )  # [C, TL]
-        gidx = tile_start + jnp.arange(tile_l)
-        w = jnp.where((gidx < n_ref[0, 0])[None, :], w_ref[0], 0.0)
-        out_ref[0] += jax.lax.dot_general(
-            w, vals, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
-
-    # tile skipping: a fully-masked tile accumulates exactly zero
-    pl.when(tile_start < n_ref[0, 0])(accumulate)
+        scalars=[nv],
+        operands=[payload, mins, shifts, w],
+        dimension_semantics=("parallel", "arbitrary"),
+    )
 
 
 def vpack_tier_out_paged(
@@ -154,7 +109,6 @@ def vpack_tier_out_paged(
     pack_size: int,
     page_size: int,
     tile_l: int = DEFAULT_TILE_L,
-    interpret: bool = True,
 ) -> Array:
     """One tier's weighted-V output over a PAGED pool.
 
@@ -171,24 +125,18 @@ def vpack_tier_out_paged(
     assert page_size % tile_l == 0 and tile_l % (pack_size * 4) == 0
     assert n_tokens % page_size == 0 and n_tokens >= page_size
     n_pg = n_tokens // page_size
-    tpp = page_size // tile_l
-
-    in_specs = [
-        pool_block_spec(payload, h_kv),
-        pool_block_spec(mins, h_kv),
-        pool_block_spec(shifts, h_kv),
-        pl.BlockSpec((1, G, tile_l), lambda b, l: (b, 0, l)),
-        pl.BlockSpec((1, 1), lambda b, l: (b, 0)),
-        page_table_spec(n_pg, h_kv),
-    ]
-    return pl.pallas_call(
-        functools.partial(_paged_kernel, width=width, pack=pack_size,
-                          tile_l=tile_l, tiles_per_page=tpp),
-        grid=(BH, n_pg * tpp),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, G, C), lambda b, l: (b, 0, 0)),
+    page = lambda x: pool_page_spec(x, h_kv, n_pg, page_size)
+    return packed_call(
+        lambda n_ref, tab_ref, *refs: _kernel(
+            n_ref, *refs, width=width, pack=pack_size, tile_l=tile_l,
+            block_l=page_size),
+        grid=(BH, n_pg),
+        in_specs=[page(payload), page(mins), page(shifts),
+                  row_block_spec(G, page_size)],
+        out_specs=row_spec((G, C)),
         out_shape=jax.ShapeDtypeStruct((BH, G, C), jnp.float32),
-        interpret=interpret,
-        **tpu_params(("parallel", "arbitrary"), interpret),
-    )(payload, mins, shifts, w,
-      n_valid.astype(jnp.int32).reshape(BH, 1), page_table[:, :n_pg])
+        scalars=[n_valid.astype(jnp.int32).reshape(BH),
+                 page_table[:, :n_pg].reshape(-1).astype(jnp.int32)],
+        operands=[payload, mins, shifts, w],
+        dimension_semantics=("parallel", "arbitrary"),
+    )

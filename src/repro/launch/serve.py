@@ -20,7 +20,7 @@ from ..configs import get_arch
 from ..core.cache import PackKVConfig
 from ..models import get_model
 from ..serving import Engine, EngineConfig, Request, SlotServer
-from ..utils import tree_bytes
+from ..utils import enable_compile_cache, tree_bytes
 
 
 def main() -> int:
@@ -107,6 +107,10 @@ def main() -> int:
     except (ValueError, AssertionError):
         raise SystemExit(f"--mesh takes 'dp,kv' (e.g. 1,2), got {args.mesh!r}")
 
+    dev = jax.devices()[0]
+    where = f"{dev.platform} {dev.device_kind}"
+    print(f"devices: {len(jax.devices())} x {where}; compile cache "
+          f"{enable_compile_cache()}")
     cfg = get_arch(args.arch, smoke=args.smoke)
     if not cfg.has_decode:
         raise SystemExit(f"{args.arch} is encoder-only; nothing to serve")
@@ -170,7 +174,7 @@ def main() -> int:
     n_tok = sum(len(r.output) for r in done)
     dt = time.time() - t0
     print(f"{args.requests} requests, {n_tok} tokens in {dt:.1f}s "
-          f"({n_tok / dt:.1f} tok/s on CPU)")
+          f"({n_tok / dt:.1f} tok/s on {where})")
     if args.session_cache:
         # returning-session demo: every request comes back with a short
         # follow-up on its full first-turn trace -> served from the park

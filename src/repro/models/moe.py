@@ -124,12 +124,10 @@ def _moe_sharded(p: dict, x: Array, cfg: ArchConfig, capacity_factor: float,
             aux = jax.lax.pmean(aux, dp if len(dp) > 1 else dp[0])
         return out.reshape(Bl, Sl, D), aux
 
-    from ..utils import shard_map_compat
-
-    f = shard_map_compat(
+    f = jax.shard_map(
         local, mesh=mesh,
         in_specs=(x_spec, P(), w_spec, w_spec, wd_spec),
-        out_specs=(x_spec, P()),
+        out_specs=(x_spec, P()), check_vma=False,
     )
     return f(x, p["router"], p["w_gate"], p["w_up"], p["w_down"])
 

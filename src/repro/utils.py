@@ -2,9 +2,13 @@
 from __future__ import annotations
 
 import dataclasses
+import os
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
+
+REPO_ROOT = Path(__file__).resolve().parents[2]
 
 
 def pytree_dataclass(cls=None, *, meta_fields: tuple[str, ...] = ()):
@@ -27,17 +31,27 @@ def pytree_dataclass(cls=None, *, meta_fields: tuple[str, ...] = ()):
     return wrap if cls is None else wrap(cls)
 
 
-def shard_map_compat(f, mesh, in_specs, out_specs):
-    """``jax.shard_map`` across jax versions: top-level (with ``check_vma``)
-    on new jax, ``jax.experimental.shard_map`` (with ``check_rep``) on
-    older releases like the 0.4.x baked into the container image."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; entry points call this
+    before their first compile (library imports never do).
 
-    return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=False)
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX has already read it and
+    nothing is changed. Otherwise the cache lives at ``<repo>/.jax_cache``:
+    a fixed path, since the directory is part of the cache key. Returns
+    the directory in use."""
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir",
+                          str(REPO_ROOT / ".jax_cache"))
+    return jax.config.jax_compilation_cache_dir
+
+
+def cpu_child_env() -> dict:
+    """Environment of a child process that simulates devices on the CPU
+    (``--xla_force_host_platform_device_count``). The platform is pinned:
+    a child that reached for an accelerator would contend with its parent,
+    which holds the chip once it has touched JAX."""
+    return {"PYTHONPATH": "src", "PATH": os.environ.get("PATH", ""),
+            "HOME": os.environ.get("HOME", ""), "JAX_PLATFORMS": "cpu"}
 
 
 def cdiv(a: int, b: int) -> int:

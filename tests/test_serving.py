@@ -12,10 +12,21 @@ from repro.models import get_model
 from repro.serving import Engine, EngineConfig, Request, SlotServer
 
 
+def _seed0_params(cfg):
+    """Smoke weights from PRNGKey(0) as drawn before JAX 0.5 turned on
+    ``jax_threefry_partitionable``, which changed the bits a key yields.
+    The greedy-agreement tests below hold only where no decode step has a
+    top-2 logit margin within the paths' bf16 noise; the newer draw puts a
+    one-ulp margin (exact policy vs rel_scale 0.005) and an exact bf16 tie
+    (chunked vs monolithic admission) on their steps."""
+    with jax.threefry_partitionable(False):
+        return get_model(cfg).init(jax.random.PRNGKey(0), cfg)
+
+
 @pytest.fixture(scope="module")
 def llama_engine():
     cfg = SMOKES["llama2-7b"]
-    params = get_model(cfg).init(jax.random.PRNGKey(0), cfg)
+    params = _seed0_params(cfg)
     return Engine(cfg, params, PackKVConfig(),
                   EngineConfig(capacity=256, max_batch=2, calib_tokens=128)), cfg
 
@@ -40,7 +51,7 @@ def test_exact_policy_agrees_with_tight_compression(rng):
     """At rel_scale→0 the PackKV engine must produce the same greedy tokens
     as the uncompressed engine."""
     cfg = SMOKES["llama2-7b"]
-    params = get_model(cfg).init(jax.random.PRNGKey(0), cfg)
+    params = _seed0_params(cfg)
     toks = jnp.asarray(rng.integers(0, cfg.vocab, (1, 64)), jnp.int32)
     ecfg = EngineConfig(capacity=256, max_batch=1, calib_tokens=128)
     e_none = Engine(cfg, params, PackKVConfig(policy="none"), ecfg)

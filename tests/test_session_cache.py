@@ -38,6 +38,7 @@ from repro.configs import SMOKES
 from repro.core.cache import PackKVConfig, SessionStore
 from repro.models import get_model
 from repro.serving import Engine, EngineConfig, Request, SlotServer
+from repro.utils import cpu_child_env
 
 PAGE = 128
 
@@ -322,7 +323,7 @@ def test_session_hit_matches_cold_on_mesh():
     r = subprocess.run(
         [sys.executable, "-c", _SHARDED_CHILD], capture_output=True,
         text=True,
-        env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin", "HOME": "/root"},
+        env=cpu_child_env(),
         cwd=".", timeout=900,
     )
     lines = [l for l in r.stdout.splitlines() if l.startswith("RESULT ")]

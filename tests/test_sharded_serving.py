@@ -29,6 +29,7 @@ from repro.configs import SMOKES
 from repro.core.cache import PackKVConfig
 from repro.models import get_model
 from repro.serving import Engine, EngineConfig
+from repro.utils import cpu_child_env
 
 _CHILD = r"""
 import os, sys, json
@@ -124,7 +125,7 @@ def _run_child(scenario):
     r = subprocess.run(
         [sys.executable, "-c", _CHILD, json.dumps(scenario)],
         capture_output=True, text=True,
-        env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin", "HOME": "/root"},
+        env=cpu_child_env(),
         cwd=".", timeout=900,
     )
     lines = [l for l in r.stdout.splitlines() if l.startswith("RESULT ")]
