@@ -95,6 +95,7 @@ def kpack_tier_scores(
     return packed_call(
         functools.partial(_kernel, width=width, pack=pack_size,
                           tile_l=tile_l, block_l=L),
+        name="packkv_kpack_scores",
         grid=(BH, 1),
         in_specs=[row_spec(payload.shape[1:]), row_spec(mins.shape[1:]),
                   row_spec(shifts.shape[1:]), row_spec((G, C))],
@@ -140,6 +141,7 @@ def kpack_tier_scores_paged(
         lambda n_ref, tab_ref, *refs: _kernel(
             n_ref, *refs, width=width, pack=pack_size, tile_l=tile_l,
             block_l=page_size),
+        name="packkv_kpack_scores_paged",
         grid=(BH, n_pg),
         in_specs=[page(payload), page(mins), page(shifts), row_spec((G, C))],
         out_specs=row_block_spec(G, page_size),

@@ -50,6 +50,9 @@ Array = jax.Array
 
 NEG_INF = -1e30
 DEFAULT_TILE_L = 256
+# the kernels' names in compiled programs (and so in a profile's ops)
+DENSE_KERNEL = "packkv_attention"
+PAGED_KERNEL = "packkv_paged_attention"
 
 
 def _flash_tile_body(
@@ -186,7 +189,27 @@ def _offsets(counts) -> tuple[int, ...]:
     return (0, *[sum(counts[: i + 1]) for i in range(len(counts))])
 
 
-def _launch(q, kc, vc, n_comp, sm_scale, *, tile_l, block_l, n_blocks,
+def paged_grid(batch: int, h_kv: int, n_tokens: int,
+               page_size: int) -> tuple[int, int]:
+    """The paged kernel's grid: one row per (batch row, kv head), one step
+    per logical page of the ``n_tokens`` launch bucket."""
+    assert n_tokens % page_size == 0, (n_tokens, page_size)
+    return batch * h_kv, n_tokens // page_size
+
+
+def paged_grid_steps(n_comp, h_kv: int, n_tokens: int,
+                     page_size: int) -> tuple[int, int]:
+    """(grid steps, dead steps) of one paged launch whose rows hold
+    ``n_comp`` compressed tokens each (host ints, 0 for a free row). A step
+    is dead when its page lies past its row's last live page: the index map
+    re-addresses that page and every tile of the step is skipped, but the
+    step still runs."""
+    rows, n_pg = paged_grid(len(n_comp), h_kv, n_tokens, page_size)
+    live = h_kv * sum(min(-(-int(n) // page_size), n_pg) for n in n_comp)
+    return rows * n_pg, rows * n_pg - live
+
+
+def _launch(q, kc, vc, n_comp, sm_scale, *, name, tile_l, block_l, grid,
             leaf_spec, operands, metas, table=None):
     """Shared launch of ``_attention_kernel``: ``leaf_spec`` blocks a tier
     leaf, ``operands(tc)`` lists a cache's (payload, mins, shifts) leaves,
@@ -198,6 +221,7 @@ def _launch(q, kc, vc, n_comp, sm_scale, *, tile_l, block_l, n_blocks,
     h_kv = kc.chan_perm.shape[-2]
     G = H // h_kv
     BH = B * h_kv
+    assert grid[0] == BH, (grid, BH)
     pack = kc.spec.pack_size
     Dv = vc.spec.head_dim
     assert block_l % tile_l == 0 and tile_l % (pack * 4) == 0, (block_l, tile_l)
@@ -243,7 +267,8 @@ def _launch(q, kc, vc, n_comp, sm_scale, *, tile_l, block_l, n_blocks,
     kscale, kzero, vscale, vzero = metas
     acc, zsum, m, lsum = packed_call(
         kernel,
-        grid=(BH, n_blocks),
+        name=name,
+        grid=grid,
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
@@ -293,7 +318,8 @@ def fused_packed_attention(
     flat = lambda a: a.reshape(B * h_kv, *a.shape[2:])
     meta = lambda a: a.reshape(B * h_kv, 1, L)
     return _launch(
-        q, kc, vc, n_comp, sm_scale, tile_l=tile_l, block_l=L, n_blocks=1,
+        q, kc, vc, n_comp, sm_scale, name=DENSE_KERNEL, tile_l=tile_l,
+        block_l=L, grid=(B * h_kv, 1),
         leaf_spec=lambda x: row_spec(x.shape[1:]),
         operands=lambda tc: [flat(x) for x in _tier_leaves(tc)],
         metas=[meta(kc.scale), meta(kc.zero), meta(vc.scale), meta(vc.zero)],
@@ -326,14 +352,14 @@ def fused_packed_attention_paged(
     B = q.shape[0]
     h_kv = kc.scale.shape[0]
     tile_l = min(tile_l, page_size)
-    assert n_tokens % page_size == 0, (n_tokens, page_size)
-    n_pg = n_tokens // page_size
+    grid = paged_grid(B, h_kv, n_tokens, page_size)
+    n_pg = grid[1]
     # per-token metadata is rank-1 and bucket-sized: gather it dense outside
     meta = lambda a: gather_page_meta(
         a, page_table, n_tokens, page_size).reshape(B * h_kv, 1, n_tokens)
     return _launch(
-        q, kc, vc, n_comp, sm_scale, tile_l=tile_l, block_l=page_size,
-        n_blocks=n_pg,
+        q, kc, vc, n_comp, sm_scale, name=PAGED_KERNEL, tile_l=tile_l,
+        block_l=page_size, grid=grid,
         leaf_spec=lambda x: pool_page_spec(x, h_kv, n_pg, page_size),
         operands=_tier_leaves,
         metas=[meta(kc.scale), meta(kc.zero), meta(vc.scale), meta(vc.zero)],

@@ -51,12 +51,13 @@ def _padded_bytes(shape, dtype) -> int:
     return int(np.prod(shape[:-2], dtype=np.int64)) * rows * lanes * itemsize
 
 
-def packed_call(kernel, *, grid, in_specs, out_specs, out_shape, scalars,
-                operands, dimension_semantics):
+def packed_call(kernel, *, name, grid, in_specs, out_specs, out_shape,
+                scalars, operands, dimension_semantics):
     """``pallas_call`` of a packed kernel: ``scalars`` ride in SMEM as scalar
     prefetch (index maps and kernel body both read them), ``operands`` are
     blocked by ``in_specs``. The VMEM limit is the double-buffered blocks
-    plus ``VMEM_HEADROOM``."""
+    plus ``VMEM_HEADROOM``. ``name`` names the kernel's custom call in the
+    compiled program, so a profile finds it by name."""
     listed = lambda x: list(x) if isinstance(x, (list, tuple)) else [x]
     blocks = sum(
         _padded_bytes(s.block_shape, x.dtype)
@@ -71,6 +72,7 @@ def packed_call(kernel, *, grid, in_specs, out_specs, out_shape, scalars,
             in_specs=in_specs, out_specs=out_specs),
         out_shape=out_shape,
         interpret=interpret_mode(),
+        name=name,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=dimension_semantics, vmem_limit_bytes=vmem),
     )(*scalars, *operands)

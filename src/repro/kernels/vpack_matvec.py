@@ -86,6 +86,7 @@ def vpack_tier_out(
     return packed_call(
         functools.partial(_kernel, width=width, pack=pack_size,
                           tile_l=tile_l, block_l=L),
+        name="packkv_vpack_out",
         grid=(BH, 1),
         in_specs=[row_spec(payload.shape[1:]), row_spec(mins.shape[1:]),
                   row_spec(shifts.shape[1:]), row_block_spec(G, L)],
@@ -130,6 +131,7 @@ def vpack_tier_out_paged(
         lambda n_ref, tab_ref, *refs: _kernel(
             n_ref, *refs, width=width, pack=pack_size, tile_l=tile_l,
             block_l=page_size),
+        name="packkv_vpack_out_paged",
         grid=(BH, n_pg),
         in_specs=[page(payload), page(mins), page(shifts),
                   row_block_spec(G, page_size)],

@@ -43,7 +43,7 @@ from __future__ import annotations
 import dataclasses
 import time
 from collections import deque
-from functools import partial
+from functools import partial, wraps
 
 import jax
 import jax.numpy as jnp
@@ -60,6 +60,22 @@ from ..distributed.fault import FaultPlan, StragglerMonitor
 from ..models import get_model
 
 Array = jax.Array
+
+
+def span(name: str, **args):
+    """The host span ``packkv.<name>`` in any ``jax.profiler`` trace, on
+    the device trace's clock (about a microsecond when no trace runs)."""
+    return jax.profiler.TraceAnnotation(f"packkv.{name}", **args)
+
+
+def _named(fn, name: str):
+    """``fn`` under ``name``: jit names its XLA module ``jit_<name>``."""
+    @wraps(fn)
+    def call(*args, **kwargs):
+        return fn(*args, **kwargs)
+
+    call.__name__ = call.__qualname__ = name
+    return call
 
 
 @dataclasses.dataclass
@@ -221,31 +237,36 @@ class Engine:
         )
         self._prefill = self._lane_jit(
             partial(self.api.prefill, cfg=cfg, pack_cfg=self.pack_cfg,
-                    capacity=ecfg.capacity)
+                    capacity=ecfg.capacity),
+            name="packkv_prefill",
         )
         # one compile per launch bucket (bounded: core.cache.bucket_set)
         self._decode = self._lane_jit(
             partial(self.api.decode_step, cfg=cfg, backend=ecfg.backend),
-            static=("n_bucket",),
+            name="packkv_decode", static=("n_bucket",),
         )
         # one compile per distinct prompt length; slot index is traced
         self._insert = self._lane_jit(
             partial(self.api.prefill_into_slot, cfg=cfg,
-                    pack_cfg=self.pack_cfg, capacity=ecfg.capacity)
+                    pack_cfg=self.pack_cfg, capacity=ecfg.capacity),
+            name="packkv_insert",
         )
-        self._reset = self._lane_jit(self.api.reset_slot)
-        self._mask_free = self._lane_jit(self.api.mask_free)
+        self._reset = self._lane_jit(self.api.reset_slot, name="packkv_reset")
+        self._mask_free = self._lane_jit(self.api.mask_free,
+                                         name="packkv_mask_free")
         # chunked interleaved admission: one bounded prefill chunk per
         # scheduler step (one compile per distinct (chunk length, offset)).
         # The chunk scratch is raw full-head K/V and carries no cache, so
         # it stays a plain replicated jit even on a mesh.
         self._chunk_step = jax.jit(
-            partial(self.api.prefill_chunk, cfg=cfg, pack_cfg=self.pack_cfg),
+            _named(partial(self.api.prefill_chunk, cfg=cfg,
+                           pack_cfg=self.pack_cfg), "packkv_chunk_step"),
             static_argnames=("n_ctx",),
         )
         self._chunk_insert = self._lane_jit(
             partial(self.api.prefill_chunk_insert, cfg=cfg,
-                    pack_cfg=self.pack_cfg, capacity=ecfg.capacity)
+                    pack_cfg=self.pack_cfg, capacity=ecfg.capacity),
+            name="packkv_chunk_insert",
         )
 
         def _chunk_final_fn(params, cache, slot, scratch, tokens, n_ctx):
@@ -263,6 +284,7 @@ class Engine:
         # chunk_step + chunk_insert, and no scratch round-trip, on the last
         # step of every multi-chunk admission
         self._chunk_final = self._lane_jit(_chunk_final_fn,
+                                           name="packkv_chunk_final",
                                            static=("n_ctx",))
         if ecfg.prefix_cache:
             from ..core.cache import acquire_pages, release_pages
@@ -271,28 +293,32 @@ class Engine:
             self._insert_prefix = self._lane_jit(
                 partial(self.api.prefill_prefix, cfg=cfg,
                         pack_cfg=self.pack_cfg, capacity=ecfg.capacity),
-                static=("n_prefix",),
+                name="packkv_insert_prefix", static=("n_prefix",),
             )
             # interleaved prefix admission: the same per-page segments,
             # one dispatch each (mini-cache round-trips between them)
             self._prefix_chunk_init = self._lane_jit(
                 partial(self.api.prefix_chunk_init, cfg=cfg,
                         pack_cfg=self.pack_cfg, capacity=ecfg.capacity),
+                name="packkv_prefix_chunk_init",
                 static=("n_prefix", "prompt_len"),
             )
             self._prefix_chunk = self._lane_jit(
                 partial(self.api.prefix_chunk, cfg=cfg,
                         pack_cfg=self.pack_cfg),
-                static=("n_ctx",),
+                name="packkv_prefix_chunk", static=("n_ctx",),
             )
             self._prefix_chunk_insert = self._lane_jit(
                 partial(self.api.prefix_chunk_insert, pack_cfg=self.pack_cfg),
+                name="packkv_prefix_chunk_insert",
                 static=("n_prefix", "prompt_len"),
             )
             # index pin/unpin ops take sentinel-padded fixed-length id
             # vectors, so each compiles exactly once
-            self._acquire_pages = self._lane_jit(acquire_pages)
-            self._release_pages = self._lane_jit(release_pages)
+            self._acquire_pages = self._lane_jit(
+                acquire_pages, name="packkv_acquire_pages")
+            self._release_pages = self._lane_jit(
+                release_pages, name="packkv_release_pages")
             self._dummy_perm = jnp.broadcast_to(
                 jnp.arange(cfg.hd, dtype=jnp.int32),
                 (cfg.n_layers, cfg.n_kv_heads, cfg.hd),
@@ -315,6 +341,7 @@ class Engine:
             # (models/*.verify_steps), so one dispatch per spec step.
             self._verify = self._lane_jit(
                 partial(self.api.decode_verify, cfg=cfg, backend=ecfg.backend),
+                name="packkv_verify",
                 static=("n_bucket",),
                 donate=("cache",),
             )
@@ -335,11 +362,11 @@ class Engine:
             # one compile per (live pages, shared-prefix pages) pair — the
             # same specialization granularity as prompt-length admission
             self._evacuate = self._lane_jit(
-                self.api.evacuate_slot,
+                self.api.evacuate_slot, name="packkv_evacuate",
                 static=("n_pages", "n_shared"),
             )
             self._restore = self._lane_jit(
-                self.api.restore_slot,
+                self.api.restore_slot, name="packkv_restore",
                 static=("n_pages", "n_shared"),
             )
         if self.api.decode_multi is not None:
@@ -348,6 +375,7 @@ class Engine:
             # up to ``decode_chunk`` tokens
             self._decode_multi = self._lane_jit(
                 partial(self.api.decode_multi, cfg=cfg, backend=ecfg.backend),
+                name="packkv_decode_multi",
                 static=("t_max", "n_bucket"),
                 donate=("cache",),
             )
@@ -355,11 +383,12 @@ class Engine:
             self._decode_multi = None
 
     # -- mesh lanes ---------------------------------------------------------
-    def _lane_jit(self, fn, *, static=(), donate=()):
-        """jit one serving dispatch; on a mesh, the body runs inside a
-        shard_map lane (kernels/sharded.py) with cache-spec-derived in/out
-        specs. Off-mesh (``mesh_shape == (1, 1)``) this is exactly
-        ``jax.jit(fn)`` — the pre-mesh engine, byte for byte.
+    def _lane_jit(self, fn, *, name, static=(), donate=()):
+        """jit one serving dispatch as the XLA program ``jit_<name>`` (the
+        name a profile shows); on a mesh, the body runs inside a shard_map
+        lane (kernels/sharded.py) with cache-spec-derived in/out specs.
+        Off-mesh (``mesh_shape == (1, 1)``) this is ``jax.jit(fn)`` under
+        that name — the pre-mesh engine's compiled code, byte for byte.
 
         Mechanics on a mesh: the wrapper binds the caller's args against
         ``fn``'s signature, closes over the static (python) args, derives
@@ -372,7 +401,8 @@ class Engine:
         model code queries via ``active_lane()``.
         """
         if self.mesh is None:
-            return jax.jit(fn, static_argnames=static, donate_argnames=donate)
+            return jax.jit(_named(fn, name), static_argnames=static,
+                           donate_argnames=donate)
         import inspect
 
         from ..distributed.sharding import serving_specs
@@ -394,6 +424,7 @@ class Engine:
             return sharded_call(body, mesh, in_specs, out_specs)(*vals)
 
         mesh_fn.__signature__ = sig  # so jit resolves static/donated names
+        mesh_fn.__name__ = mesh_fn.__qualname__ = name
         return jax.jit(mesh_fn, static_argnames=static, donate_argnames=donate)
 
     def _arg_specs(self, name, val):
@@ -455,19 +486,26 @@ class Engine:
 
         The ``cache`` argument is DONATED: the caller must drop its
         reference and use the returned cache. Returns
-        (tokens np [t_max, B], n_exec int, cache).
+        (tokens np [t_max, B], n_exec int, cache). Spans: the dispatch
+        (``packkv.decode.dispatch``) and the host's wait for its tokens
+        (``packkv.decode.wait``, which records ``n_exec``).
         """
-        toks, n_exec, cache = self._decode_multi(
-            self.params,
-            cache=cache,
-            token=token,
-            active=jnp.asarray(active, bool),
-            n_steps=jnp.int32(n_steps),
-            eos_id=jnp.int32(-1 if eos_id is None else eos_id),
-            t_max=self.ecfg.decode_chunk,
-            n_bucket=n_bucket,
-        )
-        return np.asarray(toks), int(n_exec), cache
+        bucket = self.ecfg.capacity if n_bucket is None else n_bucket
+        with span("decode.dispatch", bucket=bucket, n_steps=n_steps):
+            toks, n_exec, cache = self._decode_multi(
+                self.params,
+                cache=cache,
+                token=token,
+                active=jnp.asarray(active, bool),
+                n_steps=jnp.int32(n_steps),
+                eos_id=jnp.int32(-1 if eos_id is None else eos_id),
+                t_max=self.ecfg.decode_chunk,
+                n_bucket=n_bucket,
+            )
+        with span("decode.wait") as wait:
+            toks, n_exec = np.asarray(toks), int(n_exec)
+            wait.set_metadata(n_exec=n_exec)
+        return toks, n_exec, cache
 
     def decode_verify(self, cache, tokens: np.ndarray, lens: np.ndarray,
                       active, n_bucket: int | None = None):
@@ -747,6 +785,13 @@ class SlotStats:
     # chunked admission telemetry (zeros when prefill_chunk_pages == 0):
     prefill_chunks: int = 0  # bounded prefill dispatches (single-chunk
     # plain prompts take the fused monolithic launch and count zero)
+    prefill_tokens: int = 0  # prompt tokens run through prefill dispatches
+    #   (every admission path; a prefix hit's shared pages are not run)
+    # paged Pallas attention work (zeros unless paged + backend "pallas"),
+    # counted over executed steps x layers from the host counter mirror:
+    attn_grid_steps: int = 0  # grid steps the paged kernel ran
+    attn_dead_steps: int = 0  # ... whose page lies past its row's live
+    #   compressed pages (every step of a free row included)
     # prefix-cache telemetry (zeros when the feature is off):
     prefix_lookups: int = 0  # admissions that consulted the prefix index
     prefix_hits: int = 0  # admissions that matched >= 1 full page
@@ -1735,20 +1780,37 @@ class SlotServer:
                 if match_pages:
                     self.stats.prefix_hits += 1
                     self.stats.prefix_pages_shared += len(match_pages)
+                self.stats.prefill_tokens += len(req.tokens) - \
+                    len(match_pages) * self.engine.ecfg.page_size
                 logits, self.cache = self.engine.insert_request_prefix(
                     self.cache, i, req.tokens, match_pages, match_perms
                 )
                 self._slot_shared[i] = tuple(int(p) for p in match_pages)
                 self._register(req, i)
             else:
-                logits, self.cache = self.engine.insert_request(
-                    self.cache, i, req.tokens
-                )
-            self._activate(req, i, int(jnp.argmax(logits)))
+                with self._prefill_span(req, "insert", len(req.tokens)):
+                    logits, self.cache = self.engine.insert_request(
+                        self.cache, i, req.tokens
+                    )
+            self._activate(req, i, self._first_token(req, logits))
             self._check_invariants()
             if self.slots[i].done:  # max_new == 1 or instant EOS
                 finished.append(self._retire_slot(i))
         return finished
+
+    def _prefill_span(self, req: Request, kind: str, n_tokens: int,
+                      n_ctx: int = 0):
+        """Count a plain prefill dispatch's prompt tokens; its span
+        (``kind``: insert, chunk or final)."""
+        self.stats.prefill_tokens += n_tokens
+        return span("prefill", rid=req.rid, kind=kind, tokens=n_tokens,
+                    n_ctx=n_ctx)
+
+    def _first_token(self, req: Request, logits) -> int:
+        """An admission's first token: the host waits here for its
+        prefill."""
+        with span("prefill.wait", rid=req.rid):
+            return int(jnp.argmax(logits))
 
     def _activate(self, req: Request, i: int, tok: int) -> None:
         """Occupy slot ``i`` with ``req`` whose first token is ``tok``."""
@@ -1833,10 +1895,11 @@ class SlotServer:
         else:
             c = self.engine.chunk_tokens()
             if S <= c:  # single-chunk prompt: fused fast path
-                logits, self.cache = self.engine.insert_request(
-                    self.cache, slot, req.tokens
-                )
-                self._activate(req, slot, int(jnp.argmax(logits)))
+                with self._prefill_span(req, "insert", S):
+                    logits, self.cache = self.engine.insert_request(
+                        self.cache, slot, req.tokens
+                    )
+                self._activate(req, slot, self._first_token(req, logits))
                 self._check_invariants()
                 if self.slots[slot].done:  # max_new == 1 or instant EOS
                     finished.append(self._retire_slot(slot))
@@ -1867,15 +1930,18 @@ class SlotServer:
             seg = t.req.tokens[s0:s1]
             if t.kind == "plain":
                 if t.idx == len(t.bounds) - 2:  # last segment: fused insert
-                    t.logits, self.cache = self.engine.chunk_final(
-                        self.cache, t.slot, t.scratch, seg, s0
-                    )
+                    with self._prefill_span(t.req, "final", s1 - s0, s0):
+                        t.logits, self.cache = self.engine.chunk_final(
+                            self.cache, t.slot, t.scratch, seg, s0
+                        )
                     t.scratch = None
                 else:
-                    t.logits, t.scratch = self.engine.chunk_step(
-                        t.scratch, seg, s0
-                    )
+                    with self._prefill_span(t.req, "chunk", s1 - s0, s0):
+                        t.logits, t.scratch = self.engine.chunk_step(
+                            t.scratch, seg, s0
+                        )
             else:
+                self.stats.prefill_tokens += s1 - s0
                 t.logits, t.scratch = self.engine.prefix_chunk_step(
                     t.scratch, seg, s0
                 )
@@ -1894,7 +1960,7 @@ class SlotServer:
             self._slot_shared[i] = t.match_pages
             self._register(t.req, i)
         # plain rows were already scattered by the fused final chunk
-        self._activate(t.req, i, int(jnp.argmax(t.logits)))
+        self._activate(t.req, i, self._first_token(t.req, t.logits))
         self._check_invariants()
         if self.slots[i].done:  # max_new == 1 or instant EOS
             finished.append(self._retire_slot(i))
@@ -2069,21 +2135,26 @@ class SlotServer:
         t0 = time.perf_counter()
         self._step_no += 1
         finished: list[Request] = []
-        self._apply_faults(finished)
-        self._reap(finished)
-        if self.engine.ecfg.prefill_chunk_pages > 0:
-            self._advance_task(finished)
-        else:
-            finished.extend(self._admit())
-        if self.n_occupied:
-            t_dec = time.perf_counter()
-            if self.engine.ecfg.spec_decode and not self._spec_degraded:
-                self._decode_spec(finished)
-            else:
-                if self.engine.ecfg.spec_decode:
-                    self.stats.degraded_steps += 1
-                self._decode_plain(finished)
-            self._observe_launch(time.perf_counter() - t_dec)
+        queued = sum(len(q) for q in self.queues.values())
+        with span("step", step=self._step_no, occupied=self.n_occupied,
+                  queued=queued):
+            self._apply_faults(finished)
+            with span("reap"):
+                self._reap(finished)
+            with span("admit"):
+                if self.engine.ecfg.prefill_chunk_pages > 0:
+                    self._advance_task(finished)
+                else:
+                    finished.extend(self._admit())
+            if self.n_occupied:
+                t_dec = time.perf_counter()
+                if self.engine.ecfg.spec_decode and not self._spec_degraded:
+                    self._decode_spec(finished)
+                else:
+                    if self.engine.ecfg.spec_decode:
+                        self.stats.degraded_steps += 1
+                    self._decode_plain(finished)
+                self._observe_launch(time.perf_counter() - t_dec)
         if self._sessions is not None:  # mirror store-side eviction counts
             self.stats.session_evictions = (
                 self._sessions.evictions + self._sessions.expired
@@ -2094,14 +2165,18 @@ class SlotServer:
     def _decode_plain(self, finished: list[Request]) -> None:
         """The non-speculative launch: donated multi-step chunk or a
         single bucketed decode step."""
-        n_steps, n_bucket = self._chunk_plan()
-        if self.engine.ecfg.decode_chunk > 1 and \
-                self.engine._decode_multi is not None:
-            self._decode_chunk(n_steps, n_bucket, finished)
+        chunked = self.engine.ecfg.decode_chunk > 1 and \
+            self.engine._decode_multi is not None
+        with span("decode.plan"):
+            n_steps, n_bucket = self._chunk_plan()
+            rows = self._rows_ahead(n_steps if chunked else 1)
+        if chunked:
+            self._decode_chunk(n_steps, n_bucket, rows, finished)
         else:
-            self._decode_single(n_bucket, finished)
+            self._decode_single(n_bucket, rows, finished)
 
-    def _decode_single(self, n_bucket: int | None, finished: list[Request]):
+    def _decode_single(self, n_bucket: int | None, rows: list[list[int]],
+                       finished: list[Request]):
         """PR-2 style per-token launch (decode_chunk=1), optionally bucketed."""
         tok = jnp.asarray(self._last_tok[:, None])
         logits, self.cache = self.engine.decode(self.cache, tok, n_bucket)
@@ -2109,6 +2184,7 @@ class SlotServer:
         now = time.perf_counter()
         self.stats.decode_steps += 1
         self.stats.chunk_launches += 1
+        self._count_attn(rows, n_bucket)
         self._log_launch(1, n_bucket)
         for i, act in enumerate(self.slots):
             if act is None:
@@ -2143,7 +2219,7 @@ class SlotServer:
             self.cache = self.engine.mask_free(self.cache, active)
 
     def _decode_chunk(self, n_steps: int, n_bucket: int | None,
-                      finished: list[Request]):
+                      rows: list[list[int]], finished: list[Request]):
         """Donated multi-step launch: up to ``n_steps`` tokens per row.
 
         Rows that emit EOS mid-chunk keep decoding (their later tokens are
@@ -2159,7 +2235,16 @@ class SlotServer:
         self.stats.chunk_launches += 1
         self.stats.decode_steps += n_exec
         self.stats.occupied_slot_steps += n_exec * self.n_occupied
+        grid, dead = self._count_attn(rows[:n_exec], n_bucket)
         self._log_launch(n_exec, n_bucket)
+        with span("decode.emit", n_exec=n_exec, grid_steps=grid,
+                  dead_steps=dead):
+            self._emit_chunk(toks, n_exec, now, finished)
+
+    def _emit_chunk(self, toks: np.ndarray, n_exec: int, now: float,
+                    finished: list[Request]) -> None:
+        """Hand each row its tokens from a multi-step launch; retire the
+        rows that finished."""
         for i, act in enumerate(self.slots):
             if act is None:
                 continue
@@ -2196,11 +2281,51 @@ class SlotServer:
         # counters in-graph every iteration, and _retire resets the rows
         # freed just now, so the cache already satisfies the invariant
 
+    # -- paged attention work -------------------------------------------------
+    def _paged_kernel(self) -> bool:
+        """Decode attention runs the paged Pallas kernel."""
+        e = self.engine
+        return e.ecfg.paged and e.ecfg.backend == "pallas" and \
+            e.pack_cfg.policy != "none"
+
+    def _rows_ahead(self, n_steps: int) -> list[list[int]]:
+        """Per step of a launch of ``n_steps``, every row's compressed
+        tokens when that step's attention reads them (after its append);
+        0 for a free row. Empty where no paged kernel runs."""
+        if not self._paged_kernel():
+            return []
+        return [[0 if a is None else self._counters(a, s)[0]
+                 for a in self.slots] for s in range(1, n_steps + 1)]
+
+    def _count_attn(self, rows: list[list[int]], n_bucket: int | None,
+                    per_layer: int = 1) -> tuple[int, int]:
+        """Add the paged kernel's (grid, dead) steps over a launch's
+        executed steps (``rows``, from ``_rows_ahead``) to the stats, the
+        kernel running ``per_layer`` times in each layer of each step; on a
+        mesh, the whole launch's. Returns what it added."""
+        if not rows:
+            return 0, 0
+        from ..kernels.packed_attention import paged_grid_steps
+
+        e = self.engine
+        n_tokens = min(e.ecfg.capacity if n_bucket is None else n_bucket,
+                       e.ecfg.capacity)
+        grid = dead = 0
+        for n_comp in rows:
+            g, d = paged_grid_steps(n_comp, e.cfg.n_kv_heads, n_tokens,
+                                    e.ecfg.page_size)
+            grid, dead = grid + g, dead + d
+        k = e.cfg.n_layers * per_layer
+        self.stats.attn_grid_steps += k * grid
+        self.stats.attn_dead_steps += k * dead
+        return k * grid, k * dead
+
     # -- speculative decode --------------------------------------------------
-    def _counters(self, act: _Active) -> tuple[int, int]:
-        """Host-mirrored (n_comp, n_resid) for an occupied slot — exact,
-        zero device syncs. The device counters are a deterministic function
-        of prompt length and cached-token count: prefill flushes every full
+    def _counters(self, act: _Active, ahead: int = 0) -> tuple[int, int]:
+        """Host-mirrored (n_comp, n_resid) for an occupied slot, after
+        ``ahead`` more appends — exact, zero device syncs. The device
+        counters are a deterministic function of prompt length and
+        cached-token count: prefill flushes every full
         block (``n_comp = Lb``), then each cached decode token appends one
         residual slot with a block flush whenever the residual hits R at
         append start (paged rows stop flushing once the compressed region
@@ -2213,11 +2338,12 @@ class SlotServer:
         pack = self.engine.pack_cfg
         if act.base is not None:
             lb, r0, cached0, _ = act.base
-            r = r0 + (act.cached_tokens - cached0)
+            r = r0 + (act.cached_tokens + ahead - cached0)
         else:
             S = len(act.req.tokens)
             lb = (S // pack.block) * pack.block
-            r = S - lb + len(act.out) - 1  # residual had no flush ever fired
+            # the residual as if no flush had ever fired
+            r = S - lb + len(act.out) - 1 + ahead
         f = 0
         if r > pack.residual:  # flushes fire as soon as r crosses R
             f = -(-(r - pack.residual) // pack.block)
@@ -2295,8 +2421,8 @@ class SlotServer:
         # it (the plain chunk path can flush mid-chunk, so it must bound by
         # total tokens; this tighter bound is speculation-only and is a
         # real fraction of the verify win at long residuals)
-        n_comp_max = 1
-        for a in self.slots:
+        n_comp = [0] * self.n_slots
+        for i, a in enumerate(self.slots):
             if a is None:
                 continue
             c, r = self._counters(a)
@@ -2304,8 +2430,8 @@ class SlotServer:
                     not self.engine.ecfg.paged or
                     c + self.engine.pack_cfg.block <= self.engine.ecfg.capacity):
                 c += self.engine.pack_cfg.block  # the seed append flushes
-            n_comp_max = max(n_comp_max, c)
-        n_bucket = self.engine.bucket_for(n_comp_max)
+            n_comp[i] = c
+        n_bucket = self.engine.bucket_for(max([1, *n_comp]))
         active = [s is not None for s in self.slots]
         # one dispatch: verify + accept + commit + free-row masking (the
         # commit lands in-graph BEFORE the retire resets below, so a
@@ -2317,6 +2443,8 @@ class SlotServer:
         self.stats.decode_steps += 1
         self.stats.chunk_launches += 1
         self.stats.spec_launches += 1
+        if self._paged_kernel():  # one kernel launch per window position
+            self._count_attn([n_comp], n_bucket, per_layer=w)
         self._log_launch(1, n_bucket)
         for i, act in enumerate(self.slots):
             if act is None:
