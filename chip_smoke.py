@@ -76,12 +76,19 @@ def _requests(cfg, lengths, max_new):
             for i in range(8)]
 
 
-def _engine(cfg, params, backend, sz, mesh=(1, 1), calibrate=True):
+def _engine(cfg, params, backend, sz, mesh=(1, 1), calibrate=True,
+            width=None):
+    """``width``: one tier of that width for K and for V (with
+    ``calibrate=False``), in place of the default (2, 4, 8)."""
     from repro.core.cache import PackKVConfig
     from repro.serving import Engine, EngineConfig
 
+    pack = PackKVConfig(policy="packkv")
+    if width is not None:
+        pack = dataclasses.replace(pack, k_tiers=(width,), k_fracs=(1.0,),
+                                   v_tiers=(width,), v_fracs=(1.0,))
     t0 = time.perf_counter()
-    eng = Engine(cfg, params, PackKVConfig(policy="packkv"), EngineConfig(
+    eng = Engine(cfg, params, pack, EngineConfig(
         capacity=sz["capacity"], max_batch=MAX_BATCH, backend=backend,
         paged=True, page_size=sz["page"], bucketed=False,
         prefill_chunk_pages=max(sz["prompts"]) // sz["page"],
@@ -89,7 +96,7 @@ def _engine(cfg, params, backend, sz, mesh=(1, 1), calibrate=True):
     ks, vs = eng.pack_cfg.k_spec(cfg.hd), eng.pack_cfg.v_spec(cfg.hd)
     print(f"[{backend} mesh {mesh}] engine built in "
           f"{time.perf_counter() - t0:.1f}s (calibration compile included); "
-          f"{'calibrated' if calibrate else 'default'} K tiers "
+          f"{'calibrated' if calibrate else 'static'} K tiers "
           f"{ks.widths}x{ks.counts}, V tiers "
           f"{vs.widths}x{vs.counts}; pool {eng.pack_cfg.pool_pages} pages "
           f"of {sz['page']} tokens")
@@ -229,12 +236,15 @@ def one_chip(cfg, api, sz, dev, where) -> None:
         gc.collect()
         print(f"[{backend}] device 0 memory: {_memory(dev)}")
 
-    # calibration on random weights keeps one 4-bit tier; the default
-    # (2, 4, 8)-bit tiers put the narrowest payloads through the kernels
-    eng = _engine(cfg, params, "pallas", sz, calibrate=False)
-    _check_attention(_first_step_logits(eng, cfg, sz)[2], cfg)
-    del eng
-    gc.collect()
+    # calibration on random weights keeps one 4-bit tier (the field-major
+    # decode); the default (2, 4, 8)-bit tiers put the narrowest payloads
+    # through the lane-repeat decode, one tier of each other width through
+    # the field-major decode at that width
+    for width in (None, 1, 2, 8, 16):
+        eng = _engine(cfg, params, "pallas", sz, calibrate=False, width=width)
+        _check_attention(_first_step_logits(eng, cfg, sz)[2], cfg)
+        del eng
+        gc.collect()
 
     p, x = res["pallas"], res["xla"]
     _check(np.array_equal(p["pre"], x["pre"]),

@@ -22,7 +22,11 @@ from ..core.tiered import (
 )
 from . import ref
 from .kpack_matvec import kpack_tier_scores, kpack_tier_scores_paged
-from .packed_attention import fused_packed_attention, fused_packed_attention_paged
+from .packed_attention import (
+    DEFAULT_TILE_L,
+    fused_packed_attention,
+    fused_packed_attention_paged,
+)
 from .pallas_utils import rows_valid
 from .vpack_matvec import vpack_tier_out, vpack_tier_out_paged
 
@@ -270,7 +274,7 @@ def packed_decode_attention(
     sm_scale: float,
     *,
     backend: str = "xla",
-    tile_l: int = 256,
+    tile_l: int = DEFAULT_TILE_L,
 ) -> Array:
     """Full decode attention over [compressed | residual] regions."""
     if backend == "xla":
@@ -291,7 +295,7 @@ def paged_decode_attention(
     *,
     n_bucket: int | None = None,
     backend: str = "xla",
-    tile_l: int = 256,
+    tile_l: int = DEFAULT_TILE_L,
 ) -> Array:
     """Full decode attention over a PAGED compressed cache + residual.
 

@@ -25,6 +25,15 @@ only in their block specs (``pallas_utils``):
   scale/zero are gathered to the dense layout outside the kernel (rank-1
   metadata, bucket-sized). With equal ``tile_l`` both modes run the same
   tiles in the same order, so their outputs are bit-identical.
+
+Token order within a tile: when every K and V tier has one width
+(``launch_pieces`` > 1), tiles decode in field-major order
+(``unpack.decode_tier_tile_fields``) and the kernel lays the tile's
+per-token scale/zero out in that order (``pallas_utils.field_major_lanes``,
+in-vreg lane gathers: reordering them outside would add a pass); the masked
+softmax and the weighted V sum are taken over the tile's tokens, so only
+the order of summation changes. Mixed widths keep the stored order and the
+lane-repeat decode (``unpack.decode_tier_tile``).
 """
 from __future__ import annotations
 
@@ -36,6 +45,7 @@ from jax.experimental import pallas as pl
 
 from ..core.tiered import TieredCache
 from .pallas_utils import (
+    field_major_lanes,
     packed_call,
     pool_page_spec,
     row_block_spec,
@@ -44,7 +54,7 @@ from .pallas_utils import (
     tile_slices,
     token_iota,
 )
-from .unpack import decode_tier_tile
+from .unpack import decode_tier_tile, decode_tier_tile_fields
 
 Array = jax.Array
 
@@ -126,6 +136,7 @@ def _attention_kernel(
     k_offs,
     v_offs,
     pack: int,
+    pieces: int,
     sm_scale: float,
     tile_l: int,
     block_l: int,
@@ -133,7 +144,10 @@ def _attention_kernel(
     """refs layout: [k_payload*nk, k_mins*nk, k_shifts*nk, kscale, kzero,
     v_payload*nv, v_mins*nv, v_shifts*nv, vscale, vzero, q,
     acc_out, zsum_out, m_out, l_out]; ``n_ref`` holds every row's
-    valid-token count (scalar prefetch)."""
+    valid-token count (scalar prefetch). ``pieces`` > 1: tiles decode in
+    field-major order (``launch_pieces``) and their metadata is reordered
+    to match."""
+    decode = decode_tier_tile_fields if pieces > 1 else decode_tier_tile
     i = 0
     k_pay = refs[i : i + nk]; i += nk
     k_min = refs[i : i + nk]; i += nk
@@ -160,8 +174,8 @@ def _attention_kernel(
         tiles = []
         for t in range(len(pays)):
             ws, ps, bs = tile_slices(s, tile_l, widths[t], pack)
-            tiles.append(decode_tier_tile(pays[t][:, ws], mins[t][:, ps],
-                                          shfs[t][:, bs], widths[t], pack))
+            tiles.append(decode(pays[t][:, ws], mins[t][:, ps],
+                                shfs[t][:, bs], widths[t], pack))
         return tiles
 
     for s in range(block_l // tile_l):
@@ -173,16 +187,39 @@ def _attention_kernel(
         # p = 0), so skip the K/V decode, both dot_generals and the update
         @pl.when(start < n_live)
         def _live_tile(s=s, start=start, tok=tok):
+            meta = [r[:, tok] for r in (kscale_ref, kzero_ref, vscale_ref,
+                                        vzero_ref)]
+            if pieces > 1:  # in the tile's token order, as one [4, TL]
+                meta = field_major_lanes(jnp.concatenate(meta, axis=0),
+                                         pieces)
+                meta = [meta[r : r + 1] for r in range(4)]
             _flash_tile_body(
                 q_ref[...],
                 tier_tiles(k_pay, k_min, k_shf, k_widths, s),
                 tier_tiles(v_pay, v_min, v_shf, v_widths, s),
-                kscale_ref[:, tok], kzero_ref[:, tok],
-                vscale_ref[:, tok], vzero_ref[:, tok],
-                n_live, token_iota(start, tile_l),
+                *meta,
+                n_live, token_iota(start, tile_l, pieces),
                 acc_ref, zsum_ref, m_ref, l_ref,
                 k_offs=k_offs, v_offs=v_offs, sm_scale=sm_scale,
             )
+
+
+def launch_pieces(k_spec, v_spec, tile_l: int) -> int:
+    """Pieces of the token order the attention kernel decodes ``tile_l``
+    tiles in for these K and V ``TierSpec``s: the field-major order of
+    their one width when every tier of both has it and ``tile_l`` is a
+    power of two (a paged launch's always is), else 1 (the stored order)."""
+    widths = {*k_spec.widths, *v_spec.widths}
+    if len(widths) != 1 or 0 in widths or tile_l & (tile_l - 1):
+        return 1
+    return 32 // widths.pop()  # the fields of a word
+
+
+def paged_pieces(k_spec, v_spec, page_size: int,
+                 tile_l: int = DEFAULT_TILE_L) -> int:
+    """``launch_pieces`` of a paged launch, whose compute tile is ``tile_l``
+    cut to the page (``fused_packed_attention_paged``)."""
+    return launch_pieces(k_spec, v_spec, min(tile_l, page_size))
 
 
 def _offsets(counts) -> tuple[int, ...]:
@@ -209,10 +246,11 @@ def paged_grid_steps(n_comp, h_kv: int, n_tokens: int,
     return rows * n_pg, rows * n_pg - live
 
 
-def _launch(q, kc, vc, n_comp, sm_scale, *, name, tile_l, block_l, grid,
-            leaf_spec, operands, metas, table=None):
-    """Shared launch of ``_attention_kernel``: ``leaf_spec`` blocks a tier
-    leaf, ``operands(tc)`` lists a cache's (payload, mins, shifts) leaves,
+def _launch(q, kc, vc, n_comp, sm_scale, *, name, tile_l, pieces, block_l,
+            grid, leaf_spec, operands, metas, table=None):
+    """Shared launch of ``_attention_kernel``: ``pieces`` is the tiles'
+    token order (``launch_pieces``), ``leaf_spec`` blocks a tier leaf,
+    ``operands(tc)`` lists a cache's (payload, mins, shifts) leaves,
     ``metas`` are the [BH, 1, n_tokens] (kscale, kzero, vscale, vzero);
     ``table`` is the flat page table of a paged launch."""
     from ..core.tiered import chan_inverse_perm
@@ -255,6 +293,7 @@ def _launch(q, kc, vc, n_comp, sm_scale, *, name, tile_l, block_l, grid,
         k_offs=_offsets(kc.spec.counts),
         v_offs=_offsets(vc.spec.counts),
         pack=pack,
+        pieces=pieces,
         sm_scale=sm_scale,
         tile_l=tile_l,
         block_l=block_l,
@@ -319,7 +358,7 @@ def fused_packed_attention(
     meta = lambda a: a.reshape(B * h_kv, 1, L)
     return _launch(
         q, kc, vc, n_comp, sm_scale, name=DENSE_KERNEL, tile_l=tile_l,
-        block_l=L, grid=(B * h_kv, 1),
+        pieces=launch_pieces(kc.spec, vc.spec, tile_l), block_l=L, grid=(B * h_kv, 1),
         leaf_spec=lambda x: row_spec(x.shape[1:]),
         operands=lambda tc: [flat(x) for x in _tier_leaves(tc)],
         metas=[meta(kc.scale), meta(kc.zero), meta(vc.scale), meta(vc.zero)],
@@ -351,6 +390,7 @@ def fused_packed_attention_paged(
 
     B = q.shape[0]
     h_kv = kc.scale.shape[0]
+    pieces = paged_pieces(kc.spec, vc.spec, page_size, tile_l)
     tile_l = min(tile_l, page_size)
     grid = paged_grid(B, h_kv, n_tokens, page_size)
     n_pg = grid[1]
@@ -359,7 +399,7 @@ def fused_packed_attention_paged(
         a, page_table, n_tokens, page_size).reshape(B * h_kv, 1, n_tokens)
     return _launch(
         q, kc, vc, n_comp, sm_scale, name=PAGED_KERNEL, tile_l=tile_l,
-        block_l=page_size, grid=grid,
+        pieces=pieces, block_l=page_size, grid=grid,
         leaf_spec=lambda x: pool_page_spec(x, h_kv, n_pg, page_size),
         operands=_tier_leaves,
         metas=[meta(kc.scale), meta(kc.zero), meta(vc.scale), meta(vc.zero)],

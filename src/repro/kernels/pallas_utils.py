@@ -122,7 +122,37 @@ def tile_slices(s: int, tile_l: int, width: int, pack: int):
         slice(s * b, (s + 1) * b)
 
 
-def token_iota(start, tile_l: int):
-    """Global token indices ``[1, tile_l]`` of a tile (2-D: Mosaic has no
-    1-D iota)."""
-    return start + jax.lax.broadcasted_iota(jnp.int32, (1, tile_l), 1)
+def token_iota(start, tile_l: int, pieces: int = 1):
+    """Global token indices ``[1, tile_l]`` of a tile's lanes (2-D: Mosaic
+    has no 1-D iota). A tile in the field-major order of ``pieces`` k
+    (``unpack.decode_tier_tile_fields``; ``tile_l`` a power of two) holds
+    token ``j·k + f`` at lane ``f·m + j``, ``m = tile_l / k``; ``pieces``
+    1 is the stored order."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, tile_l), 1)
+    if pieces > 1:
+        m = tile_l // pieces
+        lane = (lane & (m - 1)) * pieces + (lane >> (m.bit_length() - 1))
+    return start + lane
+
+
+def field_major_lanes(x, pieces: int):
+    """Rows ``[R, TL]`` of per-token values in the stored order, laid out
+    in the field-major order of ``pieces`` (lane ``f·m + j`` takes token
+    ``j·k + f``, as ``token_iota`` names them). In-kernel: lane gathers
+    within each 128-lane vreg and a select per source vreg; Mosaic gathers
+    only whole vregs of rows, so ``R`` > 1 (stack the rows to reorder)."""
+    if pieces == 1:
+        return x
+    R, TL = x.shape
+    src = jnp.broadcast_to(token_iota(0, TL, pieces), (R, TL))
+    W = min(TL, 128)
+    srcs = [x[:, d * W:(d + 1) * W] for d in range(TL // W)]
+    out = []
+    for c in range(TL // W):
+        s = src[:, c * W:(c + 1) * W]
+        y = None
+        for d, xd in enumerate(srcs):
+            g = jnp.take_along_axis(xd, s & (W - 1), axis=1)
+            y = g if y is None else jnp.where(s // W == d, g, y)
+        out.append(y)
+    return jnp.concatenate(out, axis=1) if len(out) > 1 else out[0]

@@ -792,6 +792,8 @@ class SlotStats:
     attn_grid_steps: int = 0  # grid steps the paged kernel ran
     attn_dead_steps: int = 0  # ... whose page lies past its row's live
     #   compressed pages (every step of a free row included)
+    attn_field_major_steps: int = 0  # ... that decoded their tiles in
+    #   field-major order (every K and V tier of one width)
     # prefix-cache telemetry (zeros when the feature is off):
     prefix_lookups: int = 0  # admissions that consulted the prefix index
     prefix_hits: int = 0  # admissions that matched >= 1 full page
@@ -2235,10 +2237,10 @@ class SlotServer:
         self.stats.chunk_launches += 1
         self.stats.decode_steps += n_exec
         self.stats.occupied_slot_steps += n_exec * self.n_occupied
-        grid, dead = self._count_attn(rows[:n_exec], n_bucket)
+        grid, dead, field = self._count_attn(rows[:n_exec], n_bucket)
         self._log_launch(n_exec, n_bucket)
         with span("decode.emit", n_exec=n_exec, grid_steps=grid,
-                  dead_steps=dead):
+                  dead_steps=dead, field_major_steps=field):
             self._emit_chunk(toks, n_exec, now, finished)
 
     def _emit_chunk(self, toks: np.ndarray, n_exec: int, now: float,
@@ -2298,14 +2300,14 @@ class SlotServer:
                  for a in self.slots] for s in range(1, n_steps + 1)]
 
     def _count_attn(self, rows: list[list[int]], n_bucket: int | None,
-                    per_layer: int = 1) -> tuple[int, int]:
-        """Add the paged kernel's (grid, dead) steps over a launch's
-        executed steps (``rows``, from ``_rows_ahead``) to the stats, the
-        kernel running ``per_layer`` times in each layer of each step; on a
-        mesh, the whole launch's. Returns what it added."""
+                    per_layer: int = 1) -> tuple[int, int, int]:
+        """Add the paged kernel's (grid, dead, field-major) steps over a
+        launch's executed steps (``rows``, from ``_rows_ahead``) to the
+        stats, the kernel running ``per_layer`` times in each layer of each
+        step; on a mesh, the whole launch's. Returns what it added."""
         if not rows:
-            return 0, 0
-        from ..kernels.packed_attention import paged_grid_steps
+            return 0, 0, 0
+        from ..kernels.packed_attention import paged_grid_steps, paged_pieces
 
         e = self.engine
         n_tokens = min(e.ecfg.capacity if n_bucket is None else n_bucket,
@@ -2316,9 +2318,14 @@ class SlotServer:
                                     e.ecfg.page_size)
             grid, dead = grid + g, dead + d
         k = e.cfg.n_layers * per_layer
+        # every tier of one width: the kernel decodes field-major
+        pieces = paged_pieces(e.pack_cfg.k_spec(e.cfg.hd),
+                              e.pack_cfg.v_spec(e.cfg.hd), e.ecfg.page_size)
+        field = k * grid if pieces > 1 else 0
         self.stats.attn_grid_steps += k * grid
         self.stats.attn_dead_steps += k * dead
-        return k * grid, k * dead
+        self.stats.attn_field_major_steps += field
+        return k * grid, k * dead, field
 
     # -- speculative decode --------------------------------------------------
     def _counters(self, act: _Active, ahead: int = 0) -> tuple[int, int]:

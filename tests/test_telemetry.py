@@ -1,6 +1,7 @@
 """The serving engine's telemetry: the paged attention kernel's grid-step
-counters and the prefill token counter against brute-force recounts, and
-the names its programs and kernels carry into a compiled module."""
+counters (all, dead, field-major) and the prefill token counter against
+brute-force recounts, and the names its programs and kernels carry into a
+compiled module."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -8,6 +9,7 @@ import pytest
 
 from repro.configs import SMOKES
 from repro.core.cache import PackKVConfig
+from repro.core.tiered import TierSpec
 from repro.kernels.packed_attention import PAGED_KERNEL
 from repro.models import get_model
 from repro.serving import Engine, EngineConfig, Request, SlotServer
@@ -27,12 +29,12 @@ def smoke():
     return cfg, get_model(cfg).init(jax.random.PRNGKey(0), cfg)
 
 
-def _engine(smoke, **kw):
+def _engine(smoke, pack=PackKVConfig(), **kw):
     cfg, params = smoke
     ecfg = dict(capacity=CAPACITY, max_batch=SLOTS, calib_tokens=128,
                 bucket_unit=PAGE, page_size=PAGE, decode_chunk=4)
     ecfg.update(kw)
-    return Engine(cfg, params, PackKVConfig(), EngineConfig(**ecfg))
+    return Engine(cfg, params, pack, EngineConfig(**ecfg))
 
 
 def _serve(eng, requests=REQUESTS):
@@ -84,8 +86,24 @@ def test_grid_step_counters_match_a_recount_of_the_launch_log(smoke,
     assert srv.stats.attn_grid_steps == grid
     assert srv.stats.attn_dead_steps == dead
     assert 0 < dead < grid
+    # calibration gives the smoke one 4-bit tier: every step is field-major
+    assert srv.stats.attn_field_major_steps == grid
     got = srv.stats.to_json()
     assert (got["attn_grid_steps"], got["attn_dead_steps"]) == (grid, dead)
+
+
+@pytest.mark.parametrize("widths", [(4,), (2, 4, 8)], ids=["one", "mixed"])
+def test_field_major_steps_count_single_width_launches(smoke, widths):
+    cfg, _ = smoke
+    spec = TierSpec.for_head_dim(cfg.hd, widths, (1,) if len(widths) == 1
+                                 else (0.25, 0.5, 0.25))
+    pack = PackKVConfig(k_spec_static=spec, v_spec_static=spec)
+    srv = SlotServer(_engine(smoke, backend="pallas", paged=True,
+                             calibrate=False, pack=pack))
+    grid, dead, field = srv._count_attn([[100, 0, 300]], 512)
+    assert 0 < dead < grid
+    assert field == (grid if len(widths) == 1 else 0)
+    assert srv.stats.attn_field_major_steps == field
 
 
 def test_no_grid_steps_where_no_paged_kernel_runs(smoke):

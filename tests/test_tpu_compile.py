@@ -31,6 +31,8 @@ B, H_KV, G, D = 4, 8, 3, 128
 CAPACITY, PAGE = 2048, 256
 POOL_PAGES = B * CAPACITY // PAGE
 SPEC = TierSpec(widths=(2, 4, 8), counts=(32, 64, 32))
+# what calibration picks for the benchmark's models: the field-major decode
+SPEC_4BIT = TierSpec(widths=(4,), counts=(D,))
 SM = D ** -0.5
 
 
@@ -90,7 +92,10 @@ def _launch(kernel):
     if kernel == "attention":
         return (partial(fused_packed_attention, sm_scale=SM),
                 [f32(B, H_KV * G, D), dense, dense, i32(B)])
-    if kernel == "attention_paged":
+    if kernel in ("attention_paged", "attention_paged_4bit"):
+        if kernel == "attention_paged_4bit":
+            pool = jax.eval_shape(
+                lambda: alloc_tiered_pool(B, H_KV, POOL_PAGES, PAGE, SPEC_4BIT))
         return (partial(fused_packed_attention_paged, n_tokens=CAPACITY,
                         sm_scale=SM, page_size=PAGE),
                 [f32(B, H_KV * G, D), pool, pool, table, i32(B)])
@@ -114,7 +119,8 @@ def _launch(kernel):
 
 
 @pytest.mark.parametrize("kernel", ["attention", "attention_paged", "kpack",
-                                    "kpack_paged", "vpack", "vpack_paged"])
+                                    "kpack_paged", "vpack", "vpack_paged",
+                                    "attention_paged_4bit"])
 def test_kernel_compiles_for_v5e(one_chip, compiled_kernels, kernel):
     fn, args = _launch(kernel)
     compiled = jax.jit(fn).lower(*_on(one_chip, args)).compile()
